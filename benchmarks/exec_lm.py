@@ -6,8 +6,9 @@ Each (model, scenario) row extracts its workload, solves it through the
 network pipeline, lowers the result to an ``ExecPlan`` (GEMMs on
 `kernels/matmul_int8` with mapping-derived blocks, attention score/AV on
 `kernels/flash_attention`, the SSD intra-chunk pair fused on
-`kernels/ssd_scan`) and executes it in Pallas interpret mode (CPU; pass
-``--no-interpret`` on real hardware). Every kernel invocation is checked
+`kernels/ssd_scan`) and executes it on the compiled kernels (TPU; pass
+``--interpret`` for the Pallas interpreter on a CPU). Every kernel
+invocation is checked
 against its ``ref.py`` oracle, and per-op predicted cycles are *ranked*
 against measured seconds — the Fig. 4(a) discipline, now
 model-vs-execution instead of model-vs-simulator.
@@ -19,7 +20,7 @@ already decide.
 
 Registered as the ``exec`` job in ``benchmarks.run``; standalone CLI:
 
-    PYTHONPATH=src python -m benchmarks.exec_lm --reduced
+    PYTHONPATH=src python -m benchmarks.exec_lm --reduced --interpret
     PYTHONPATH=src python -m benchmarks.exec_lm \\
         --archs minicpm-2b,mamba2-1.3b --scenarios exec_prefill
 
@@ -73,7 +74,7 @@ def run(budget_s: float = 45.0, quick: bool = False, reduced: bool = False,
         archs: tuple[str, ...] | None = None,
         scenarios: tuple[str, ...] | None = None,
         mode: str = "miredo", repeats: int = 3, seed: int = 0,
-        interpret: bool = True, workers: int | None = 1) -> dict:
+        interpret: bool = False, workers: int | None = 1) -> dict:
     quick = quick or reduced
     arch = default_arch()
     arch_ids = tuple(archs) if archs else (
@@ -81,7 +82,7 @@ def run(budget_s: float = 45.0, quick: bool = False, reduced: bool = False,
     if interpret and not reduced:
         print("[exec] WARNING: interpret mode emulates every grid step in "
               "Python — full-size configs can take hours per row; use "
-              "--reduced on CPU or --no-interpret on real hardware",
+              "--reduced on CPU or drop --interpret on real hardware",
               flush=True)
     scen = tuple(scenarios) if scenarios else tuple(EXEC_SHAPES)
     unknown = set(scen) - set(EXEC_SHAPES)
@@ -224,17 +225,19 @@ def main(argv=None) -> int:
     ap.add_argument("--repeats", type=int, default=3,
                     help="timed repeats per unique op (min is reported)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="compile the Pallas kernels for real hardware "
-                         "instead of interpret-mode CPU emulation")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels in the interpreter (CPU) "
+                         "instead of compiling them for the TPU")
     ap.add_argument("--workers", type=int, default=1,
                     help="solver processes (keep 1 once JAX is loaded)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     run(budget_s=args.budget, quick=args.quick, reduced=args.reduced,
         archs=tuple(a for a in args.archs.split(",") if a) or None,
         scenarios=tuple(s for s in args.scenarios.split(",") if s) or None,
         mode=args.mode, repeats=args.repeats, seed=args.seed,
-        interpret=not args.no_interpret, workers=args.workers)
+        interpret=args.interpret, workers=args.workers)
     return 0
 
 

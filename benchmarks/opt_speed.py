@@ -6,7 +6,7 @@ Three measurements, one JSON row (``reports/benchmarks/opt_speed.json``):
   1. **mappings/sec** on sampler pools (one GEMM, one conv): the historical
      per-candidate scalar loop (``mapping.validate`` +
      ``energy.evaluate_edp``) against the batched scorer
-     (`latency_batched.score_mappings`) on each available backend. Before
+     (`latency_batched.score_mappings`). Before
      timing, the batched scores are checked for *exact* equality with the
      scalar loop on every feasible row (infeasible rows must come back
      ``inf``) — a speedup that changes answers is a bug, not a result.
@@ -48,7 +48,7 @@ from repro.core.energy import evaluate_edp
 from repro.core.factorization import factorize_layer_dims
 from repro.core.mapping import validate
 
-#: Throughput gate: best batched/scalar ratio across pools/backends. 1.0
+#: Throughput gate: best batched/scalar ratio across pools. 1.0
 #: ("no slower than the loop it replaced") — measured margins are
 #: 1.2-1.3x on the feasible-only pool, but a single shared CI core is
 #: noisy, so the gate asserts parity and the JSON records the margin.
@@ -74,8 +74,7 @@ PORTFOLIO_SCENARIOS = ("decode_32k",)
 
 
 def _pools(quick: bool) -> list[tuple[str, object, int]]:
-    """(name, layer, pool size): one GEMM and one conv, sized so the jax
-    backend crosses its auto-dispatch threshold even in quick mode."""
+    """(name, layer, pool size): one GEMM and one conv."""
     n = 512 if quick else 2000
     return [
         ("gemm", wl.gemm("g", 32, 512, 512), n),
@@ -114,30 +113,22 @@ def _best_of(fn, repeats: int = REPEATS) -> float:
 def _check_agreement(pool, layer, arch, name: str) -> int:
     """Exact scalar/batched equality on every row; returns feasible count."""
     ref = _scalar_scores(pool, layer, arch)
-    for backend in ("numpy",) + (("jax",) if lb.HAVE_JAX else ()):
-        sc = lb.score_mappings(pool, layer, arch, backend=backend)
-        for i, (cyc, pj, edp) in enumerate(ref):
-            got = (float(sc.cycles[i]), float(sc.energy_pj[i]),
-                   float(sc.edp[i]))
-            if got != (cyc, pj, edp):
-                raise RuntimeError(
-                    f"[optspeed] {name}/{backend} row {i}: batched {got} "
-                    f"!= scalar {(cyc, pj, edp)}")
+    sc = lb.score_mappings(pool, layer, arch)
+    for i, (cyc, pj, edp) in enumerate(ref):
+        got = (float(sc.cycles[i]), float(sc.energy_pj[i]), float(sc.edp[i]))
+        if got != (cyc, pj, edp):
+            raise RuntimeError(
+                f"[optspeed] {name} row {i}: batched {got} "
+                f"!= scalar {(cyc, pj, edp)}")
     return sum(r[0] != math.inf for r in ref)
 
 
 def _race(pool, layer, arch) -> dict[str, float]:
     """Best-of-N wall seconds per contender on one pool."""
     need = ("feasible", "latency", "energy")
-    out = {"scalar": _best_of(lambda: _scalar_scores(pool, layer, arch)),
-           "batched-numpy": _best_of(lambda: lb.score_mappings(
-               pool, layer, arch, need=need, backend="numpy"))}
-    if lb.HAVE_JAX:
-        # warm the jit cache before timing: compile time is a one-off
-        lb.score_mappings(pool, layer, arch, need=need, backend="jax")
-        out["batched-jax"] = _best_of(lambda: lb.score_mappings(
-            pool, layer, arch, need=need, backend="jax"))
-    return out
+    return {"scalar": _best_of(lambda: _scalar_scores(pool, layer, arch)),
+            "batched": _best_of(lambda: lb.score_mappings(
+                pool, layer, arch, need=need))}
 
 
 def _dse_cold_warm(cache_dir: str) -> dict:
@@ -352,7 +343,7 @@ def run(budget_s: float = 0.0, quick: bool = False, dse: bool = False,
         print(f"[optspeed] {name}: agreement exact on "
               f"{min(n, 256)} rows ({feas} feasible)")
 
-    print(md_table(["pool", "backend", "n", "scalar maps/s",
+    print(md_table(["pool", "scorer", "n", "scalar maps/s",
                     "batched maps/s", "ratio"], rows))
     print(f"[optspeed] best batched/scalar ratio {best_ratio:.2f}x "
           f"({best_where}); gate >={MIN_RATIO:g}x")
@@ -361,7 +352,7 @@ def run(budget_s: float = 0.0, quick: bool = False, dse: bool = False,
             f"[optspeed] batched scorer slower than scalar everywhere "
             f"(best {best_ratio:.2f}x < {MIN_RATIO:g}x)")
 
-    payload = {"have_jax": lb.HAVE_JAX, "quick": quick,
+    payload = {"quick": quick,
                "agreement": "exact", "pools": pools_json,
                "best_ratio": round(best_ratio, 3),
                "best_ratio_pool": best_where}

@@ -86,11 +86,12 @@ def main(argv=None):
         # ranking (benchmarks/serve_sim.py).
         ("serve", lambda: serve_sim.run(budget_s=budget, quick=args.quick,
                                         reduced=args.quick)),
-        # exec always runs reduced: interpret mode emulates every grid step
-        # in Python, so full-size configs are a real-hardware exercise
-        # (benchmarks/exec_lm.py --no-interpret), not a harness target.
+        # exec always runs reduced and interpreted: interpret mode emulates
+        # every grid step in Python, so full-size configs are a chip
+        # exercise (benchmarks/exec_lm.py without --interpret), not a
+        # harness target.
         ("exec", lambda: exec_lm.run(budget_s=budget, quick=args.quick,
-                                     reduced=True)),
+                                     reduced=True, interpret=True)),
         # scalar-vs-batched throughput race + exact-agreement check; the
         # cold/warm DSE timing is its standalone --dse flag (minutes) and
         # the solver-portfolio gate its --portfolio flag.

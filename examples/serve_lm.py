@@ -31,6 +31,8 @@ def main(argv=None):
                     help="stream length for --traffic")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.traffic:
         traffic_demo(n_requests=args.n_requests, seed=args.seed)
     else:
@@ -44,8 +46,9 @@ def decode_demo():
     import numpy as np
 
     from repro.configs import get_config
-    from repro.train.steps import (StepConfig, init_train_state,
-                                   make_decode_step, make_prefill_step)
+    from repro.train.steps import (StepConfig, decode_caches,
+                                   init_train_state, make_decode_step,
+                                   make_prefill_step)
 
     cfg = get_config("glm4-9b").reduced()
     step_cfg = StepConfig(remat=False, compute_dtype=jnp.float32)
@@ -66,13 +69,7 @@ def decode_demo():
 
     t0 = time.monotonic()
     logits, caches = prefill(params, {"tokens": prompt})
-    # pad caches to max_seq so decode can append
-    def pad(t):
-        if t.ndim == 5 and t.shape[2] == prompt_len:
-            return jnp.pad(t, [(0, 0), (0, 0),
-                               (0, max_seq - prompt_len), (0, 0), (0, 0)])
-        return t
-    caches = jax.tree.map(pad, caches)
+    caches = decode_caches(cfg, caches, batch, max_seq, step_cfg.compute_dtype)
     print(f"prefill {batch}x{prompt_len}: {time.monotonic()-t0:.2f}s")
 
     toks = [jnp.argmax(logits, -1).astype(jnp.int32)[:, None]]
@@ -178,12 +175,13 @@ def report_cim_dataflow(cfg, batch: int, budget_s: float = 2.0,
     print("  dbl-buf :", mp["double_buf"])
 
     # And actually RUN the served decode step's optimized plan on the
-    # Pallas kernels (interpret mode): every GEMM on matmul_int8 with
-    # mapping-derived blocks, the decode attention step on flash_attention
-    # against the KV cache, each invocation checked against its ref.py.
+    # Pallas kernels (interpret mode: this example runs on the CPU): every
+    # GEMM on matmul_int8 with mapping-derived blocks, the decode attention
+    # step on flash_attention against the KV cache, each invocation checked
+    # against its ref.py.
     from repro.core.executor import execute_plan, lower_plan
     plan = lower_plan(cfg, spec, net, arch)
-    rep = execute_plan(plan)
+    rep = execute_plan(plan, interpret=True)
     rank = f"{rep.rank_corr:.2f}" if rep.rank_corr is not None else "n/a"
     print(f"measured execution: {rep.n_unique} unique kernels "
           f"({rep.n_ops} ops), {rep.measured_total_s * 1e3:.1f} ms "

@@ -204,8 +204,7 @@ class SearchResult:
 
 def heuristic_search(layer: wl.Layer, arch: CimArch, budget: int = 2000,
                      seed: int = 0, accurate: bool = False,
-                     k_min: int = 3, alpha: float = 0.15,
-                     backend: str | None = None) -> SearchResult:
+                     k_min: int = 3, alpha: float = 0.15) -> SearchResult:
     """ZigZag-style mapper. ``accurate=False`` ranks candidates with the
     idealized perfect-overlap model (the strawman the paper criticizes);
     ``accurate=True`` ranks with the full analytical model (ablation).
@@ -213,8 +212,7 @@ def heuristic_search(layer: wl.Layer, arch: CimArch, budget: int = 2000,
     Enumerate-then-score: the whole candidate pool is sampled up front and
     ranked in one batched dispatch (`latency_batched.score_mappings` —
     bit-equal to the scalar oracle, so the winner, its cost and the
-    feasible count are identical to the historical per-candidate loop).
-    ``backend`` forwards to the batched scorer ("jax"/"numpy"/auto)."""
+    feasible count are identical to the historical per-candidate loop)."""
     import numpy as np
 
     from repro.core import latency_batched as lb
@@ -225,7 +223,7 @@ def heuristic_search(layer: wl.Layer, arch: CimArch, budget: int = 2000,
     cands = [sample_mapping_raw(layer, arch, rng, factors)
              for _ in range(budget)]
     need = ("feasible", "latency") if accurate else ("feasible", "ideal")
-    sc = lb.score_mappings(cands, layer, arch, need=need, backend=backend)
+    sc = lb.score_mappings(cands, layer, arch, need=need)
     best, best_cost = None, math.inf
     feas = int(sc.feasible.sum()) if budget else 0
     if feas:

@@ -32,9 +32,9 @@ allows" demands:
      with the segment that will execute it (`Schedule.stage_segment_ids`).
   2. **Execution.** Each structurally unique op runs once with warm-up plus
      timed repeats (operand *values* are synthetic; shapes, dtypes and
-     block shapes are exactly the plan's). ``interpret=True`` executes the
-     Pallas kernels in Python on CPU so CI exercises the whole path; on
-     real hardware pass ``interpret=False``.
+     block shapes are exactly the plan's). The kernels compile for the
+     TPU; ``interpret=True`` runs them in the Pallas interpreter, which is
+     how the tests drive the whole path on a CPU.
   3. **Validation.** Every kernel invocation is checked against its
      package's ``ref.py`` oracle (`quantized_matmul_and_ref`,
      `attention_ref`, `ssd_intra_chunk_and_ref`), and measured wall-clock
@@ -103,6 +103,9 @@ class ExecOp:
     #: ``None`` for ops with no workload layer (attention score stage).
     predicted_cycles: float | None = None
     measured_s: float | None = None        # per-invocation wall-clock
+    #: Wall-clock of the numerics call: compiling the kernel and its oracle
+    #: (on first use of the shape) plus one run of each.
+    first_call_s: float | None = None
     rel_err: float | None = None           # vs the kernel's ref.py oracle
     numerics_ok: bool | None = None
 
@@ -297,6 +300,14 @@ def _rel_err(out, ref) -> float:
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-12))
 
 
+def _first_call(fn):
+    """``(fn(), seconds)`` for the first, compiling call of ``fn``."""
+    import jax
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn())
+    return out, time.perf_counter() - t0
+
+
 def _time_call(fn, warmup: int, repeats: int) -> float:
     """min-of-repeats wall-clock of ``fn()`` after ``warmup`` extra calls.
     Callers count their numerics invocation as the first warm-up (it
@@ -313,7 +324,7 @@ def _time_call(fn, warmup: int, repeats: int) -> float:
 
 
 def _run_matmul(op: ExecOp, rng, interpret: bool, warmup: int,
-                repeats: int) -> tuple[float, float]:
+                repeats: int) -> tuple[float, float, float]:
     import jax.numpy as jnp
     from repro.kernels.matmul_int8.ops import (quantized_matmul,
                                                quantized_matmul_and_ref)
@@ -322,18 +333,18 @@ def _run_matmul(op: ExecOp, rng, interpret: bool, warmup: int,
     w = jnp.asarray(rng.standard_normal((s["k"], s["n"])) * 0.1,
                     jnp.float32)
     blocks = (s["bm"], s["bk"], s["bn"])
-    out, ref = quantized_matmul_and_ref(x, w, block_shapes=blocks,
-                                        interpret=interpret)
+    (out, ref), first = _first_call(lambda: quantized_matmul_and_ref(
+        x, w, block_shapes=blocks, interpret=interpret))
     t = _time_call(
         lambda: quantized_matmul(x, w, block_shapes=blocks,
                                  interpret=interpret,
                                  out_dtype=jnp.float32),
         warmup - 1, repeats)
-    return t, _rel_err(out, ref)
+    return first, t, _rel_err(out, ref)
 
 
 def _run_flash(op: ExecOp, rng, interpret: bool, warmup: int,
-               repeats: int) -> tuple[float, float]:
+               repeats: int) -> tuple[float, float, float]:
     import jax.numpy as jnp
     from repro.kernels.flash_attention.ops import flash_attention
     from repro.kernels.flash_attention.ref import attention_ref
@@ -344,13 +355,13 @@ def _run_flash(op: ExecOp, rng, interpret: bool, warmup: int,
     call = lambda: flash_attention(q, k, v, causal=s["causal"],
                                    block_q=s["bq"], block_k=s["bk"],
                                    interpret=interpret)
-    out = call()
-    ref = attention_ref(q, k, v, causal=s["causal"])
-    return _time_call(call, warmup - 1, repeats), _rel_err(out, ref)
+    (out, ref), first = _first_call(
+        lambda: (call(), attention_ref(q, k, v, causal=s["causal"])))
+    return first, _time_call(call, warmup - 1, repeats), _rel_err(out, ref)
 
 
 def _run_ssd(op: ExecOp, rng, interpret: bool, warmup: int,
-             repeats: int) -> tuple[float, float]:
+             repeats: int) -> tuple[float, float, float]:
     import jax.numpy as jnp
     from repro.kernels.ssd_scan.ops import (ssd_intra_chunk,
                                             ssd_intra_chunk_and_ref)
@@ -362,18 +373,19 @@ def _run_ssd(op: ExecOp, rng, interpret: bool, warmup: int,
     a = -jnp.asarray(rng.uniform(0.5, 4.0, (1,)), jnp.float32)
     ss = jnp.cumsum(dt * a, axis=2)
     x = jnp.asarray(rng.standard_normal((1, 1, q, 1, p)), jnp.float32)
-    out, ref = ssd_intra_chunk_and_ref(c, b, ss, dt, x, interpret=interpret)
+    (out, ref), first = _first_call(lambda: ssd_intra_chunk_and_ref(
+        c, b, ss, dt, x, interpret=interpret))
     t = _time_call(
         lambda: ssd_intra_chunk(c, b, ss, dt, x, interpret=interpret),
         warmup - 1, repeats)
-    return t, _rel_err(out, ref)
+    return first, t, _rel_err(out, ref)
 
 
 _RUNNERS = {"matmul_int8": _run_matmul, "flash_attention": _run_flash,
             "ssd_scan": _run_ssd}
 
 
-def execute_plan(plan: ExecPlan, *, interpret: bool = True, warmup: int = 1,
+def execute_plan(plan: ExecPlan, *, interpret: bool = False, warmup: int = 1,
                  repeats: int = 2, seed: int = 0, verbose: bool = False,
                  memo: dict | None = None) -> ExecReport:
     """Execute every structurally unique op of ``plan`` (memoized by
@@ -397,10 +409,10 @@ def execute_plan(plan: ExecPlan, *, interpret: bool = True, warmup: int = 1,
             memo[op.key] = _RUNNERS[op.kernel](op, rng, interpret, warmup,
                                                repeats)
             if verbose:
-                t, e = memo[op.key]
+                f, t, e = memo[op.key]
                 print(f"[exec] {op.kernel:>16} {op.name}: {t * 1e3:.2f} ms "
-                      f"rel_err {e:.2e}")
-        op.measured_s, op.rel_err = memo[op.key]
+                      f"rel_err {e:.2e} (first call {f:.2f} s)")
+        op.first_call_s, op.measured_s, op.rel_err = memo[op.key]
         op.numerics_ok = op.rel_err <= NUMERICS_TOL[op.kernel]
     report = ExecReport(
         plan=plan,
@@ -418,7 +430,7 @@ def execute_model(cfg, spec, arch: CimArch | None = None, *,
                   mode: str = "miredo", per_layer_cap_s: float = 2.0,
                   total_budget_s: float | None = None,
                   workers: int | None = 1, net=None,
-                  interpret: bool = True, warmup: int = 1, repeats: int = 2,
+                  interpret: bool = False, warmup: int = 1, repeats: int = 2,
                   seed: int = 0, verbose: bool = False) -> ExecReport:
     """Extract -> optimize -> lower -> execute for one (model, scenario).
 

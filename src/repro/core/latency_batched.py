@@ -7,9 +7,9 @@ screening, the MIP warm-start incumbents — evaluation-bound. This module
 packs a whole candidate pool into fixed-shape arrays and replays the exact
 same arithmetic vectorized over the batch:
 
-  * the Table III recursion runs as a ``lax.scan`` over the (right-aligned,
-    identity-padded) slot axis with the three per-operand rows unrolled in
-    ``OPERANDS`` order,
+  * the Table III recursion runs over the (right-aligned, identity-padded)
+    slot axis with the three per-operand rows unrolled in ``OPERANDS``
+    order,
   * one-time fills, energy traffic, the idealized perfect-overlap bound and
     the eq. (9) capacity feasibility are left-folds over padded hop/level
     axes in the scalar evaluation order.
@@ -18,8 +18,8 @@ The scalar model remains the oracle: packing reads the *shared* slot
 analysis (`latency.operand_transfer_table` via ``analyze_slots`` /
 ``operand_fill_hops``, `energy.operand_energy_hops`,
 `latency.idealized_terms`, `mapping.capacity_usage`), every float op is
-replayed in the scalar order under float64 (``jax.experimental.enable_x64``),
-and padding is provably inert (an identity slot — n=1, no transfers — maps
+replayed in the scalar order under float64 NumPy, and padding is provably
+inert (an identity slot — n=1, no transfers — maps
 the P vector through unchanged; padded hops add ``+ 0.0``). Total cycles,
 energy and EDP are therefore *bit-equal* to the scalar oracle, which the
 differential sweep in ``tests/test_latency_batched.py`` enforces.
@@ -29,12 +29,6 @@ sampler-constructed candidate (`baselines.sample_mapping_raw`) can violate;
 structural legality (factor products, spatial axis membership, monotone
 level assignment, C^M) holds for such candidates by construction. For
 arbitrary mappings run ``mapping.validate`` instead.
-
-JAX is optional at runtime: without it (or with ``backend="numpy"``) a
-NumPy reference loop evaluates the identical IEEE-754 operation sequence.
-On CPU the two backends agree bitwise; the jitted path amortizes dispatch
-over the batch (recompiles are bounded by bucketing the slot axis to
-multiples of 4).
 """
 
 from __future__ import annotations
@@ -50,19 +44,6 @@ from repro.core.energy import operand_energy_hops
 from repro.core.latency import (analyze_slots, idealized_terms,
                                 operand_fill_hops, operand_transfer_table)
 from repro.core.mapping import Mapping, capacity_usage, size_context
-
-try:                                                    # pragma: no cover
-    import jax
-    from jax.experimental import enable_x64 as _enable_x64
-    HAVE_JAX = True
-except Exception:                                       # pragma: no cover
-    jax = None
-    HAVE_JAX = False
-
-#: Auto-backend cutover: below this pool size the NumPy reference loop wins
-#: (per-dispatch jit overhead dominates); above it the jitted scan wins.
-#: Both backends are bit-identical, so this is purely a speed knob.
-_JAX_MIN_BATCH = 256
 
 #: Everything the packer can materialize; trim to skip host-side analysis
 #: work the consumer does not need (e.g. the idealized-model heuristic pass
@@ -117,19 +98,9 @@ class BatchScores:
 
 
 def _slot_width(n: int) -> int:
-    """Bucket the slot axis to multiples of 4 so the jitted evaluator sees
-    a handful of shapes across a run instead of one per pool."""
+    """Bucket the slot axis to multiples of 4 (identity padding is inert,
+    `tests/test_latency_batched.py` pins that)."""
     return max(4, -(-n // 4) * 4)
-
-
-def _batch_width(b: int) -> int:
-    """Bucket the batch axis to the next power of two (>= 16) so varying
-    pool sizes reuse a handful of jit-compiled shapes; the evaluator pads
-    by replicating row 0 and slices the results back to the real batch."""
-    w = 16
-    while w < b:
-        w *= 2
-    return w
 
 
 def pack(mappings: Sequence[Mapping], layer: wl.Layer, arch: CimArch, *,
@@ -273,50 +244,50 @@ def pack(mappings: Sequence[Mapping], layer: wl.Layer, arch: CimArch, *,
 _IS_IW = (True, True, False)
 
 
-def _recursion_step(xp, carry, nf_i, t_i, dbl_i):
+def _recursion_step(carry, nf_i, t_i, dbl_i):
     """One slot of the Table III recursion, operands unrolled in scalar
-    order. ``xp`` is ``numpy`` or ``jax.numpy``; shapes (B,) / (B,3)."""
+    order; shapes (B,) / (B,3)."""
     l_next, n_next, p_next = carry
-    combined = xp.zeros_like(l_next)
+    combined = np.zeros_like(l_next)
     for j in range(3):
         tj, pj, dj = t_i[:, j], p_next[:, j], dbl_i[:, j]
-        br = xp.where(tj == 0.0, pj,
-                      xp.where(dj, xp.maximum(tj, pj), tj + pj))
-        combined = xp.maximum(combined, br)
-    l_i = xp.maximum(l_next * n_next, combined)
+        br = np.where(tj == 0.0, pj,
+                      np.where(dj, np.maximum(tj, pj), tj + pj))
+        combined = np.maximum(combined, br)
+    l_i = np.maximum(l_next * n_next, combined)
     ps = []
     for j, iw in enumerate(_IS_IW):
         tj, pj, dj = t_i[:, j], p_next[:, j], dbl_i[:, j]
-        no_t = l_i * xp.maximum(nf_i - 1.0, 0.0) + pj
+        no_t = l_i * np.maximum(nf_i - 1.0, 0.0) + pj
         if iw:
-            single = l_i * xp.maximum(nf_i - 2.0, 0.0) + 2.0 * tj + pj
-            double = xp.maximum(
-                l_i * xp.maximum(nf_i - 3.0, 0.0) + 2.0 * tj
-                + xp.maximum(tj, pj), tj * nf_i)
+            single = l_i * np.maximum(nf_i - 2.0, 0.0) + 2.0 * tj + pj
+            double = np.maximum(
+                l_i * np.maximum(nf_i - 3.0, 0.0) + 2.0 * tj
+                + np.maximum(tj, pj), tj * nf_i)
         else:
-            single = l_i * xp.maximum(nf_i - 1.0, 0.0) + 2.0 * tj + pj
-            double = l_i * xp.maximum(nf_i - 2.0, 0.0) + tj \
-                + xp.maximum(tj, l_i) + xp.maximum(tj, pj)
-        ps.append(xp.where(tj == 0.0, no_t, xp.where(dj, double, single)))
-    return l_i, nf_i, xp.stack(ps, axis=1)
+            single = l_i * np.maximum(nf_i - 1.0, 0.0) + 2.0 * tj + pj
+            double = l_i * np.maximum(nf_i - 2.0, 0.0) + tj \
+                + np.maximum(tj, l_i) + np.maximum(tj, pj)
+        ps.append(np.where(tj == 0.0, no_t, np.where(dj, double, single)))
+    return l_i, nf_i, np.stack(ps, axis=1)
 
 
-def _aggregate(xp, p_final, fill_c, e_term, ideal_num, ideal_bw,
-               compute, sizes, caps, shared, mac_pj):
+def _aggregate(p_final, fill_c, e_term, ideal_num, ideal_bw, compute,
+               sizes, caps, shared, mac_pj):
     """Post-recursion left-folds, all in the scalar evaluation order."""
-    p_max = xp.maximum(xp.maximum(p_final[:, 0], p_final[:, 1]),
+    p_max = np.maximum(np.maximum(p_final[:, 0], p_final[:, 1]),
                        p_final[:, 2])
-    one_time = xp.zeros_like(p_max)
+    one_time = np.zeros_like(p_max)
     for j in range(3):
-        s = xp.zeros_like(p_max)
+        s = np.zeros_like(p_max)
         for h in range(fill_c.shape[1]):
             s = s + fill_c[:, h, j]
         one_time = one_time + s
     cycles = p_max + one_time
 
-    traffic = xp.zeros_like(p_max)
+    traffic = np.zeros_like(p_max)
     for j in range(3):
-        s = xp.zeros_like(p_max)
+        s = np.zeros_like(p_max)
         for h in range(e_term.shape[1]):
             s = s + e_term[:, h, j]
         traffic = traffic + s
@@ -325,98 +296,35 @@ def _aggregate(xp, p_final, fill_c, e_term, ideal_num, ideal_bw,
 
     ideal = compute
     for k in range(ideal_num.shape[1]):
-        ideal = xp.maximum(ideal, ideal_num[:, k] / ideal_bw[:, k])
+        ideal = np.maximum(ideal, ideal_num[:, k] / ideal_bw[:, k])
 
     tol = caps + 1e-9
-    ssum = xp.zeros_like(caps)
-    ok_each = xp.ones(caps.shape, dtype=bool)
+    ssum = np.zeros_like(caps)
+    ok_each = np.ones(caps.shape, dtype=bool)
     for j in range(3):
         ssum = ssum + sizes[:, :, j]
         ok_each = ok_each & (sizes[:, :, j] <= tol)
-    ok = xp.where(shared[None, :], ssum <= tol, ok_each)
-    feasible = xp.all(ok, axis=1)
+    ok = np.where(shared[None, :], ssum <= tol, ok_each)
+    feasible = np.all(ok, axis=1)
     return cycles, energy, edp, ideal, feasible
 
 
-def _eval_numpy(pb: PackedBatch) -> tuple:
-    """Reference backend: the scalar op sequence, vectorized over B."""
+def evaluate_batch(pb: PackedBatch) -> BatchScores:
+    """Evaluate a packed batch: the scalar op sequence, vectorized over B."""
     B = pb.batch
     l_mvm = float(pb.arch.l_mvm_cycles)
     carry = (np.full(B, l_mvm), np.ones(B), np.full((B, 3), l_mvm))
     for i in range(pb.nf.shape[1] - 1, -1, -1):
-        carry = _recursion_step(np, carry, pb.nf[:, i], pb.t[:, i, :],
+        carry = _recursion_step(carry, pb.nf[:, i], pb.t[:, i, :],
                                 pb.dbl[:, i, :])
     mac_pj = pb.layer.macs * pb.arch.mac_energy_pj
-    return _aggregate(np, carry[2], pb.fill_c, pb.e_term,
-                      pb.ideal_num, pb.ideal_bw, pb.compute, pb.sizes,
-                      pb.caps, pb.shared, mac_pj)
-
-
-if HAVE_JAX:                                            # pragma: no branch
-    import jax.numpy as jnp
-    from jax import lax
-
-    @jax.jit
-    def _eval_jax_core(nf, t, dbl, fill_c, e_term, ideal_num,
-                       ideal_bw, compute, sizes, caps, shared, l_mvm,
-                       mac_pj):
-        B = nf.shape[0]
-        carry = (jnp.full((B,), l_mvm, dtype=jnp.float64),
-                 jnp.ones((B,), dtype=jnp.float64),
-                 jnp.full((B, 3), l_mvm, dtype=jnp.float64))
-
-        def step(c, xs):
-            nf_i, t_i, dbl_i = xs
-            return _recursion_step(jnp, c, nf_i, t_i, dbl_i), None
-
-        # innermost slot first: scan the slot axis in reverse
-        xs = (jnp.swapaxes(nf, 0, 1), jnp.swapaxes(t, 0, 1),
-              jnp.swapaxes(dbl, 0, 1))
-        carry, _ = lax.scan(step, carry, xs, reverse=True)
-        return _aggregate(jnp, carry[2], fill_c, e_term, ideal_num,
-                          ideal_bw, compute, sizes, caps, shared, mac_pj)
-
-    def _eval_jax(pb: PackedBatch) -> tuple:
-        B = pb.batch
-        Bp = _batch_width(B)
-
-        def padb(a):
-            if a.shape[0] == Bp:
-                return a
-            return np.concatenate(
-                [a, np.repeat(a[:1], Bp - a.shape[0], axis=0)], axis=0)
-
-        with _enable_x64():
-            out = _eval_jax_core(
-                padb(pb.nf), padb(pb.t), padb(pb.dbl), padb(pb.fill_c),
-                padb(pb.e_term), padb(pb.ideal_num), padb(pb.ideal_bw),
-                padb(pb.compute), padb(pb.sizes), padb(pb.caps),
-                pb.shared, float(pb.arch.l_mvm_cycles),
-                pb.layer.macs * pb.arch.mac_energy_pj)
-        return tuple(np.asarray(x)[:B] for x in out)
-
-
-def evaluate_batch(pb: PackedBatch, backend: str | None = None
-                   ) -> BatchScores:
-    """Evaluate a packed batch. ``backend``: "jax" | "numpy" | None (auto:
-    jax when importable and the pool is large enough to amortize dispatch).
-    Both backends execute the same float64 op sequence and return
-    bit-identical arrays, so the choice never changes results."""
-    if backend is None:
-        backend = "jax" if HAVE_JAX and pb.batch >= _JAX_MIN_BATCH \
-            else "numpy"
-    if backend == "jax":
-        if not HAVE_JAX:
-            raise RuntimeError("jax backend requested but jax is missing")
-        cyc, en, edp, ideal, feas = _eval_jax(pb)
-    elif backend == "numpy":
-        cyc, en, edp, ideal, feas = _eval_numpy(pb)
-    else:
-        raise ValueError(backend)
+    cyc, en, edp, ideal, feas = _aggregate(
+        carry[2], pb.fill_c, pb.e_term, pb.ideal_num, pb.ideal_bw,
+        pb.compute, pb.sizes, pb.caps, pb.shared, mac_pj)
     if pb.gated:
         # gated packs hold identity padding in infeasible rows
-        bad = ~np.asarray(feas)
-        cyc, en, edp, ideal = (np.where(bad, np.inf, np.asarray(x))
+        bad = ~feas
+        cyc, en, edp, ideal = (np.where(bad, np.inf, x)
                                for x in (cyc, en, edp, ideal))
     has = pb.need
     return BatchScores(
@@ -424,12 +332,12 @@ def evaluate_batch(pb: PackedBatch, backend: str | None = None
         energy_pj=en if "energy" in has else None,
         edp=edp if ("latency" in has and "energy" in has) else None,
         idealized=ideal if "ideal" in has else None,
-        feasible=np.asarray(feas) if "feasible" in has else None)
+        feasible=feas if "feasible" in has else None)
 
 
 def score_mappings(mappings: Sequence[Mapping], layer: wl.Layer,
-                   arch: CimArch, *, need: Sequence[str] = ALL_NEEDS,
-                   backend: str | None = None) -> BatchScores:
+                   arch: CimArch, *, need: Sequence[str] = ALL_NEEDS
+                   ) -> BatchScores:
     """Pack + evaluate in one call — the enumerate-then-score entry point
     used by `baselines.heuristic_search`, `dse.screen_arch` and the MIP
     warm-start incumbent pools."""
@@ -437,5 +345,4 @@ def score_mappings(mappings: Sequence[Mapping], layer: wl.Layer,
         z = np.zeros(0)
         return BatchScores(cycles=z, energy_pj=z, edp=z, idealized=z,
                            feasible=np.zeros(0, dtype=bool))
-    return evaluate_batch(pack(mappings, layer, arch, need=need),
-                          backend=backend)
+    return evaluate_batch(pack(mappings, layer, arch, need=need))

@@ -355,9 +355,9 @@ def optimize_network(layers: Sequence[wl.Layer], arch: CimArch | None = None,
                  ws_of.get(layer_cache_key(l)),
                  portfolio if is_mip else None) for l in order]
         if nw > 1 and len(jobs) > 1:
-            # spawn, not fork: the batched analytical model runs jax in the
-            # parent, and forking a multithreaded jax process deadlocks the
-            # children (os.fork() + jax's internal threads).
+            # spawn, not fork: the caller may hold JAX (its threads, and on
+            # a TPU host the chip), which a forked child would inherit. The
+            # solver never imports JAX, so spawned children never load it.
             with concurrent.futures.ProcessPoolExecutor(
                     max_workers=nw,
                     mp_context=multiprocessing.get_context("spawn")) as ex:

@@ -29,12 +29,15 @@ from __future__ import annotations
 import dataclasses
 import math
 
+from repro.chips import chip
 from repro.core.mip.model import LinExpr, MipModel, Status
 
-# TPU v5e per-core budgets
-VMEM_BYTES = 64 * 1024 * 1024      # usable VMEM budget (conservative half)
-HBM_BW = 819e9
-MXU_FLOPS = 197e12                 # bf16; int8 ~2x but stay conservative
+_CHIP = chip()
+#: VMEM budget for the blocks eq. 9 counts: half of one core's VMEM (the
+#: kernels request more, `chips.VMEM_LIMIT_BYTES`).
+VMEM_BYTES = _CHIP.vmem_bytes // 2
+HBM_BW = _CHIP.hbm_bw
+MXU_FLOPS = _CHIP.bf16_flops       # bf16; int8 ~2x but stay conservative
 LANE = 128
 SUBLANE = 8
 

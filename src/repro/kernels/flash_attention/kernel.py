@@ -17,12 +17,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.chips import VMEM_LIMIT_BYTES
+
 NEG_INF = -1e30
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   block_q: int, block_k: int, seq_k: int, sm_scale: float,
-                  causal: bool):
+                  causal: bool, precision):
     qi = pl.program_id(1)
     kv_step = pl.program_id(2)
 
@@ -41,6 +43,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=precision,
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale
         if causal:
@@ -52,7 +55,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
             p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=precision, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     if causal:
@@ -73,18 +76,25 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
                        block_q: int = 256, block_k: int = 256,
                        causal: bool = True,
-                       interpret: bool = True) -> jax.Array:
-    """q, k, v: (BH, L, hd) -> (BH, L, hd)."""
+                       interpret: bool = False) -> jax.Array:
+    """q, k, v: (BH, L, hd) -> (BH, L, hd).
+
+    The dots run in the precision of the inputs: f32 inputs at HIGHEST
+    (the TPU's default passes f32 operands through bf16), bf16 inputs at
+    the default, which is exact for them."""
     bh, lq, hd = q.shape
     lk = k.shape[1]
     block_q = min(block_q, lq)
     block_k = min(block_k, lk)
     assert lq % block_q == 0 and lk % block_k == 0
     sm_scale = 1.0 / math.sqrt(hd)
+    precision = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 \
+        else None
     grid = (bh, lq // block_q, lk // block_k)
     return pl.pallas_call(
         functools.partial(_flash_kernel, block_q=block_q, block_k=block_k,
-                          seq_k=lk, sm_scale=sm_scale, causal=causal),
+                          seq_k=lk, sm_scale=sm_scale, causal=causal,
+                          precision=precision),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda b, i, s: (b, i, 0)),
@@ -98,5 +108,7 @@ def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(q, k, v)

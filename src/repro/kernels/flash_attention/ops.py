@@ -21,7 +21,7 @@ def legal_block(l: int, requested: int) -> int:
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 256,
-                    block_k: int = 256, interpret: bool = True) -> jax.Array:
+                    block_k: int = 256, interpret: bool = False) -> jax.Array:
     """q, k, v: (B, L, H, hd) with H already GQA-expanded. Block sizes are
     clamped to exact divisors of L (`legal_block`)."""
     b, l, h, hd = q.shape

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.chips import VMEM_LIMIT_BYTES
+
 
 def _matmul_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref, *,
                    n_k_steps: int):
@@ -31,18 +33,16 @@ def _matmul_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)
-    w = w_ref[...].astype(jnp.int32)
+    # int8 operands straight into the MXU; Mosaic has no int32 x int32 dot
     acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
+        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
 
     @pl.when(k_step == n_k_steps - 1)
     def _finish():
-        scale = sx_ref[...].astype(jnp.float32)[:, None] * \
-            sw_ref[...].astype(jnp.float32)[None, :]
-        o_ref[...] = (acc_ref[...].astype(jnp.float32) * scale).astype(
-            o_ref.dtype)
+        # same multiply order as ref.py, so the result is bit-identical
+        out = acc_ref[...].astype(jnp.float32) * sx_ref[...] * sw_ref[...]
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret",
@@ -52,7 +52,11 @@ def matmul_int8(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
                 bn: int = 256, out_dtype=jnp.bfloat16,
                 interpret: bool = False) -> jax.Array:
     """x_q: (M, K) int8; w_q: (K, N) int8; x_scale: (M,) f32;
-    w_scale: (N,) f32 -> (M, N) out_dtype."""
+    w_scale: (N,) f32 -> (M, N) out_dtype.
+
+    The scales enter the kernel as (M, 1) columns and (1, N) rows: a rank-1
+    (bm,) block is only legal on the chip when bm is a multiple of 128 or
+    the whole dim, a (bm, 1) block whenever bm is a multiple of 8."""
     m, k = x_q.shape
     k2, n = w_q.shape
     assert k == k2
@@ -64,11 +68,14 @@ def matmul_int8(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, s: (i, s)),
             pl.BlockSpec((bk, bn), lambda i, j, s: (s, j)),
-            pl.BlockSpec((bm,), lambda i, j, s: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, s: (j,)),
+            pl.BlockSpec((bm, 1), lambda i, j, s: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, s: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(x_q, w_q, x_scale, w_scale)
+    )(x_q, w_q, x_scale.astype(jnp.float32).reshape(m, 1),
+      w_scale.astype(jnp.float32).reshape(1, n))

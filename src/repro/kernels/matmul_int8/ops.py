@@ -11,14 +11,14 @@ from repro.kernels.matmul_int8.ref import matmul_int8_ref, quantize_rowwise
 
 def quantized_matmul(x: jax.Array, w: jax.Array, *,
                      block_shapes: tuple[int, int, int] | None = None,
-                     use_kernel: bool = True, interpret: bool = True,
+                     use_kernel: bool = True, interpret: bool = False,
                      out_dtype=jnp.bfloat16) -> jax.Array:
     """bf16/f32 (M,K) @ (K,N) via INT8 quantization (CIM-style W8A8).
 
     ``block_shapes`` come from the MIREDO TPU bridge
     (core/tpu_bridge.py:select_matmul_blocks); defaults are MXU-aligned.
-    ``interpret=True`` executes the Pallas kernel in Python on CPU (this
-    container has no TPU); on real hardware pass interpret=False.
+    The kernel compiles for the TPU; ``interpret=True`` runs it in the
+    Pallas interpreter instead, which is how it runs on a CPU.
     """
     m, k = x.shape
     _, n = w.shape
@@ -44,15 +44,15 @@ def quantized_matmul(x: jax.Array, w: jax.Array, *,
 
 def quantized_matmul_and_ref(x: jax.Array, w: jax.Array, *,
                              block_shapes: tuple[int, int, int] | None = None,
-                             interpret: bool = True,
+                             interpret: bool = False,
                              out_dtype=jnp.float32
                              ) -> tuple[jax.Array, jax.Array]:
     """Kernel and pure-jnp oracle on identical quantized operands.
 
     The measured-execution backend (`core/executor.py`) checks every kernel
-    invocation against its ``ref.py``; both paths quantize the same way, so
-    the int32 accumulations are bit-identical and only the final scale
-    multiply can differ by float rounding. Returns ``(kernel, ref)``."""
+    invocation against its ``ref.py``; both paths quantize the same way and
+    apply the scales in the same order, so the two are bit-identical.
+    Returns ``(kernel, ref)``."""
     out = quantized_matmul(x, w, block_shapes=block_shapes, use_kernel=True,
                            interpret=interpret, out_dtype=out_dtype)
     ref = quantized_matmul(x, w, use_kernel=False, out_dtype=out_dtype)

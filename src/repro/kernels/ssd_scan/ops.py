@@ -10,7 +10,7 @@ from repro.kernels.ssd_scan.kernel import ssd_intra_chunk_bh
 
 def ssd_intra_chunk(c: jax.Array, b: jax.Array, s: jax.Array,
                     dt: jax.Array, x: jax.Array, *,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """c, b: (B,NC,Q,H,N); s, dt: (B,NC,Q,H); x: (B,NC,Q,H,P)."""
     bsz, nc, q, h, n = c.shape
     p = x.shape[-1]
@@ -24,7 +24,7 @@ def ssd_intra_chunk(c: jax.Array, b: jax.Array, s: jax.Array,
 
 def ssd_intra_chunk_and_ref(c: jax.Array, b: jax.Array, s: jax.Array,
                             dt: jax.Array, x: jax.Array, *,
-                            interpret: bool = True
+                            interpret: bool = False
                             ) -> tuple[jax.Array, jax.Array]:
     """Kernel and pure-jnp oracle on identical inputs — the executor's
     per-invocation numerics check (`core/executor.py`). Returns

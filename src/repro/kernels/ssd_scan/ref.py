@@ -6,17 +6,20 @@ import jax.numpy as jnp
 
 
 def ssd_intra_chunk_ref(c, b, s, dt, x):
-    """c,b: (B,NC,Q,H,N); s,dt: (B,NC,Q,H); x: (B,NC,Q,H,P)."""
+    """c,b: (B,NC,Q,H,N); s,dt: (B,NC,Q,H); x: (B,NC,Q,H,P). In f32
+    (HIGHEST: the TPU's default precision would pass f32 operands through
+    bf16)."""
+    hi = jax.lax.Precision.HIGHEST
     seg = s[:, :, :, None, :] - s[:, :, None, :, :]        # (B,NC,Q,Q,H)
     q = s.shape[2]
     tri = jnp.tril(jnp.ones((q, q), bool))
     decay = jnp.where(tri[None, None, :, :, None],
                       jnp.exp(jnp.maximum(seg, -60.0)), 0.0)
     scores = jnp.einsum("bcqhn,bckhn->bcqkh", c.astype(jnp.float32),
-                        b.astype(jnp.float32))
+                        b.astype(jnp.float32), precision=hi)
     scores = scores * decay * dt[:, :, None, :, :]
-    return jnp.einsum("bcqkh,bckhp->bcqhp", scores,
-                      x.astype(jnp.float32)).astype(x.dtype)
+    return jnp.einsum("bcqkh,bckhp->bcqhp", scores, x.astype(jnp.float32),
+                      precision=hi).astype(x.dtype)
 
 
 def ssd_sequential_ref(x, dt, a, b, c, d_skip):

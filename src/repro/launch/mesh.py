@@ -10,22 +10,25 @@ a pod's ICI domain.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    # Auto axes: the sharding rules (sharding/rules.py) place tensors with
+    # with_sharding_constraint and leave propagation to the compiler.
+    # jax.make_mesh defaults to Explicit axes, under which e.g. the
+    # embedding gather raises DuplicateSpecError.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
-    """Degenerate 1-device mesh for CPU smoke tests."""
-    dev = jax.devices()
-    n = len(dev)
-    return jax.make_mesh((1, n), ("data", "model"))
+    """(data=1, model=n) mesh over every local device: one device for CPU
+    smoke tests, the whole host (e.g. a v5e 2x2) for the sharded serve."""
+    return _mesh((1, len(jax.devices())), ("data", "model"))
 
-
-# TPU v5e hardware constants (roofline targets; see launch/roofline.py)
-PEAK_BF16_FLOPS = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW = 50e9                     # bytes/s per link (~4 links usable)

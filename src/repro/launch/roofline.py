@@ -16,10 +16,15 @@ import glob
 import json
 import os
 
+from repro.chips import chip
 from repro.configs import SHAPES, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_BF16_FLOPS
 
-ICI_LINKS = 4  # usable ICI links per chip on a v5e 2D torus (bidirectional)
+# the production meshes are v5e pods (launch/mesh.py)
+_CHIP = chip()
+PEAK_BF16_FLOPS = _CHIP.bf16_flops
+HBM_BW = _CHIP.hbm_bw
+ICI_BW = _CHIP.ici_bw
+ICI_LINKS = _CHIP.ici_links
 
 
 def model_flops(arch_id: str, shape_name: str) -> float:

@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticLMData
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.runtime.fault_tolerance import HeartbeatMonitor, StragglerPolicy
 from repro.sharding.rules import make_plan
@@ -44,6 +45,7 @@ def main(argv=None):
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -61,8 +61,10 @@ def _split_proj(cfgd: dict, zxbcdt: jax.Array):
 
 def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                 c: jax.Array, d_skip: jax.Array, chunk: int = 256,
-                h0: jax.Array | None = None, use_kernel: bool = False):
-    """Chunked SSD.
+                h0: jax.Array | None = None, use_kernel: bool = False,
+                interpret: bool = False):
+    """Chunked SSD. ``interpret`` runs the ``use_kernel`` path in the
+    Pallas interpreter (CPU).
 
     x: (B, L, H, P); dt: (B, L, H); a: (H,) (negative);
     b, c: (B, L, G, N); d_skip: (H,).
@@ -86,7 +88,8 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         # Pallas kernel builds the (Q,Q) decay in VMEM from s — no
         # (B,NC,Q,Q,H) HBM tensor.
         from repro.kernels.ssd_scan.ops import ssd_intra_chunk
-        y_intra = ssd_intra_chunk(cc, bc, s, dtc, xc).astype(x.dtype)
+        y_intra = ssd_intra_chunk(cc, bc, s, dtc, xc,
+                                  interpret=interpret).astype(x.dtype)
     else:
         seg = s[:, :, :, None, :] - s[:, :, None, :, :]      # (B,NC,Q,Q,H)
         tri = jnp.tril(jnp.ones((chunk, chunk), bool))

@@ -221,3 +221,20 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int,
         memory = jnp.zeros((batch, mem, cfg.d_model), compute_dtype)
         return (kv, cross, memory)
     raise ValueError(fam)
+
+
+def decode_caches(cfg: ModelConfig, prefilled, batch: int, max_seq: int,
+                  compute_dtype=jnp.bfloat16):
+    """``init_caches`` for ``max_seq`` positions holding a prefill's caches.
+
+    The prefill step returns caches as long as its prompt; the decode step
+    appends one position per call through a one-hot(length) scatter, which
+    drops a write past the cache's end without a word. Every prefilled
+    leaf is written at the origin of its zero-initialized counterpart (and
+    keeps the prefill's dtype, which is what decode returns), so only the
+    KV sequence axis grows. Jit it to keep the caches where the prefill
+    put them."""
+    return jax.tree.map(
+        lambda z, p: jax.lax.dynamic_update_slice(
+            z.astype(p.dtype), p, (0,) * p.ndim),
+        init_caches(cfg, batch, max_seq, compute_dtype), prefilled)

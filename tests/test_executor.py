@@ -161,7 +161,7 @@ def test_lower_plan_segments_follow_schedule(dense_prefill):
 def test_execute_plan_numerics_and_memoization(ssm_prefill):
     cfg, spec, net = ssm_prefill
     plan = lower_plan(cfg, spec, net, ARCH)
-    rep = execute_plan(plan, repeats=1)
+    rep = execute_plan(plan, interpret=True, repeats=1)
     assert rep.numerics_ok
     assert rep.max_rel_err < 1e-3
     assert rep.n_checked <= rep.n_ops              # structural memoization
@@ -181,8 +181,8 @@ def test_execute_plan_deterministic_numerics(dense_prefill):
     cfg, spec, net = dense_prefill
     p1 = lower_plan(cfg, spec, net, ARCH)
     p2 = lower_plan(cfg, spec, net, ARCH)
-    execute_plan(p1, repeats=1, seed=3)
-    execute_plan(p2, repeats=1, seed=3)
+    execute_plan(p1, interpret=True, repeats=1, seed=3)
+    execute_plan(p2, interpret=True, repeats=1, seed=3)
     for a, b in zip(p1.ops, p2.ops):
         assert a.rel_err == b.rel_err
 

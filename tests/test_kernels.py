@@ -113,7 +113,8 @@ def test_matmul_mapping_derived_blocks():
     x = jnp.asarray(rng.standard_normal((96, 200)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((200, 360)) * 0.1, jnp.float32)
     out, ref = quantized_matmul_and_ref(x, w,
-                                        block_shapes=(c.bm, c.bk, c.bn))
+                                        block_shapes=(c.bm, c.bk, c.bn),
+                                        interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -264,6 +265,7 @@ def test_ssd_kernel_path_in_chunked():
     cm = jnp.asarray(rng.standard_normal((b, l, g, n)), jnp.float32)
     d = jnp.asarray(rng.standard_normal((h,)), jnp.float32)
     y0, _ = ssd_chunked(x, dt, a, bm, cm, d, chunk=32, use_kernel=False)
-    y1, _ = ssd_chunked(x, dt, a, bm, cm, d, chunk=32, use_kernel=True)
+    y1, _ = ssd_chunked(x, dt, a, bm, cm, d, chunk=32, use_kernel=True,
+                        interpret=True)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
                                rtol=2e-3, atol=2e-3)

@@ -4,7 +4,7 @@
 `energy.evaluate_edp` (DESIGN.md §Batched analytical model): every float op
 replayed in the scalar order under float64, padding provably inert. These
 tests enforce the promise with exact ``==`` — no tolerances — across random
-(layer, arch, pool) draws on both backends, including the edge cases the
+(layer, arch, pool) draws, including the edge cases the
 packing has to get right:
 
   * mixed slot counts in one pool (right-aligned identity padding),
@@ -20,9 +20,6 @@ shared-level budget regression (the fair-share fix this PR lands).
 
 import math
 import random
-
-import numpy as np
-import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -82,7 +79,6 @@ ARCHS = (
     default_arch(n_cores=4, macro_rows=256, macro_cols=64, lbuf_kb=16.0,
                  reg_bytes=512, name="lb-wide"),
 )
-BACKENDS = ("numpy",) + (("jax",) if lb.HAVE_JAX else ())
 DIM_CHOICES = (3, 8, 24, 100, 128, 360)
 
 
@@ -102,9 +98,9 @@ def _pool(layer, arch, n, seed):
         sample_mapping_raw(layer, arch, rng, factors) for _ in range(n)]
 
 
-def _assert_rows_exact(sc, pool, layer, arch, feas, backend):
+def _assert_rows_exact(sc, pool, layer, arch, feas):
     for i, mp in enumerate(pool):
-        where = f"{arch.name}/{layer.name}/{backend} row {i}"
+        where = f"{arch.name}/{layer.name} row {i}"
         if feas[i]:
             e = evaluate_edp(mp, layer, arch)
             assert float(sc.cycles[i]) == e.latency.total_cycles, where
@@ -129,10 +125,9 @@ def test_differential_sweep_exact(kind, a, b, c, ai, seed):
     layer, arch = _layer(kind, a, b, c), ARCHS[ai]
     pool = _pool(layer, arch, 24, seed)
     feas = [not validate(mp, layer, arch) for mp in pool]
-    for backend in BACKENDS:
-        sc = lb.score_mappings(pool, layer, arch, backend=backend)
-        assert list(map(bool, sc.feasible)) == feas
-        _assert_rows_exact(sc, pool, layer, arch, feas, backend)
+    sc = lb.score_mappings(pool, layer, arch)
+    assert list(map(bool, sc.feasible)) == feas
+    _assert_rows_exact(sc, pool, layer, arch, feas)
 
 
 def test_mixed_slot_counts_padding_inert():
@@ -144,17 +139,15 @@ def test_mixed_slot_counts_padding_inert():
     pool = _pool(layer, arch, 40, seed=3)
     assert len({mp.n_slots() for mp in pool}) > 1, "pool must mix widths"
     feas = [not validate(mp, layer, arch) for mp in pool]
-    for backend in BACKENDS:
-        together = lb.score_mappings(pool, layer, arch, backend=backend)
-        for i in (0, len(pool) // 2, len(pool) - 1):
-            alone = lb.score_mappings([pool[i]], layer, arch,
-                                      backend=backend)
-            for field in ("cycles", "energy_pj", "edp", "idealized"):
-                t = float(getattr(together, field)[i])
-                s = float(getattr(alone, field)[0])
-                assert t == s or (math.isinf(t) and math.isinf(s)), \
-                    (backend, i, field, t, s)
-        _assert_rows_exact(together, pool, layer, arch, feas, backend)
+    together = lb.score_mappings(pool, layer, arch)
+    for i in (0, len(pool) // 2, len(pool) - 1):
+        alone = lb.score_mappings([pool[i]], layer, arch)
+        for field in ("cycles", "energy_pj", "edp", "idealized"):
+            t = float(getattr(together, field)[i])
+            s = float(getattr(alone, field)[0])
+            assert t == s or (math.isinf(t) and math.isinf(s)), \
+                (i, field, t, s)
+    _assert_rows_exact(together, pool, layer, arch, feas)
 
 
 def test_ungated_pack_scores_infeasible_rows():
@@ -166,26 +159,13 @@ def test_ungated_pack_scores_infeasible_rows():
     pool = _pool(layer, arch, 30, seed=5)
     infeasible = [mp for mp in pool if validate(mp, layer, arch)]
     assert infeasible, "pool must contain capacity-infeasible rows"
-    for backend in BACKENDS:
-        pb = lb.pack(pool, layer, arch, need=("latency", "energy"))
-        assert not pb.gated
-        sc = lb.evaluate_batch(pb, backend=backend)
-        for i, mp in enumerate(pool):
-            e = evaluate_edp(mp, layer, arch)
-            assert float(sc.cycles[i]) == e.latency.total_cycles
-            assert float(sc.energy_pj[i]) == e.energy.total_pj
-
-
-@pytest.mark.skipif(not lb.HAVE_JAX, reason="jax not installed")
-def test_backends_bitwise_equal():
-    """numpy and jax backends agree bitwise on the whole score vector —
-    the auto-backend cutover (`_JAX_MIN_BATCH`) can never change results."""
-    layer, arch = wl.conv("lb.be", 1, 64, 64, 14, 14, 3, 3), ARCHS[3]
-    pool = _pool(layer, arch, 50, seed=11)
-    a = lb.score_mappings(pool, layer, arch, backend="numpy")
-    b = lb.score_mappings(pool, layer, arch, backend="jax")
-    for field in ("cycles", "energy_pj", "edp", "idealized", "feasible"):
-        assert np.array_equal(getattr(a, field), getattr(b, field)), field
+    pb = lb.pack(pool, layer, arch, need=("latency", "energy"))
+    assert not pb.gated
+    sc = lb.evaluate_batch(pb)
+    for i, mp in enumerate(pool):
+        e = evaluate_edp(mp, layer, arch)
+        assert float(sc.cycles[i]) == e.latency.total_cycles
+        assert float(sc.energy_pj[i]) == e.energy.total_pj
 
 
 def test_empty_and_singleton_pools():
@@ -194,11 +174,10 @@ def test_empty_and_singleton_pools():
     assert len(sc.cycles) == 0 and len(sc.feasible) == 0
     g = greedy_mapping(layer, arch)
     e = evaluate_edp(g, layer, arch)
-    for backend in BACKENDS:
-        one = lb.score_mappings([g], layer, arch, backend=backend)
-        assert bool(one.feasible[0])
-        assert float(one.cycles[0]) == e.latency.total_cycles
-        assert float(one.edp[0]) == e.edp
+    one = lb.score_mappings([g], layer, arch)
+    assert bool(one.feasible[0])
+    assert float(one.cycles[0]) == e.latency.total_cycles
+    assert float(one.edp[0]) == e.edp
 
 
 def test_assign_levels_shared_budget_regression():

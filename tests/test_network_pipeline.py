@@ -209,3 +209,30 @@ def test_stale_cache_not_served_across_configs(tmp_path):
     cache.put(solve_record_key("miredo", TINY, ARCH, a), {"stub": 1})
     assert cache.get(solve_record_key("miredo", TINY, ARCH, a)) is not None
     assert cache.get(solve_record_key("miredo", TINY, ARCH, b)) is None
+
+
+def test_solver_never_imports_jax():
+    """A spawned solver worker imports only the solver: one MIP solve in a
+    fresh interpreter must leave JAX unloaded, so no worker can load the
+    TPU library while its parent owns the chip."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from repro.core.arch import default_arch\n"
+        "from repro.core.formulation import FormulationConfig\n"
+        "from repro.core.network import _solve_job\n"
+        "from repro.core.workload import gemm\n"
+        "rec = _solve_job((gemm('g', 16, 64, 64), default_arch(), 'miredo',"
+        " FormulationConfig(time_limit_s=1.0)))\n"
+        "assert rec['mapping'], rec\n"
+        "print('jax' in sys.modules)\n")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         env={**os.environ, "PYTHONPATH": src})
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == "False"

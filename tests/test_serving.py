@@ -544,8 +544,9 @@ def test_decode_cache_sized_to_prompt_plus_gen():
     import numpy as np
 
     from repro.configs import get_config
-    from repro.train.steps import (StepConfig, init_train_state,
-                                   make_decode_step, make_prefill_step)
+    from repro.train.steps import (StepConfig, decode_caches,
+                                   init_train_state, make_decode_step,
+                                   make_prefill_step)
 
     cfg = get_config("minicpm-2b").reduced()
     step_cfg = StepConfig(remat=False, compute_dtype=jnp.float32)
@@ -559,13 +560,8 @@ def test_decode_cache_sized_to_prompt_plus_gen():
     prefill = jax.jit(make_prefill_step(cfg, step_cfg))
     decode = jax.jit(make_decode_step(cfg, step_cfg))
     logits, caches = prefill(state.params, {"tokens": prompt})
-
-    def pad(t):
-        if t.ndim == 5 and t.shape[2] == prompt_len:
-            return jnp.pad(t, [(0, 0), (0, 0),
-                               (0, max_seq - prompt_len), (0, 0), (0, 0)])
-        return t
-    caches = jax.tree.map(pad, caches)
+    caches = decode_caches(cfg, caches, batch, max_seq,
+                           step_cfg.compute_dtype)
 
     tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
     for _ in range(gen_len):

@@ -9,9 +9,9 @@ import pytest
 from repro.configs import ARCH_IDS, get_config
 from repro.models import transformer
 from repro.train.optimizer import OptimizerConfig
-from repro.train.steps import (StepConfig, TrainState, init_caches,
-                               init_train_state, make_decode_step,
-                               make_prefill_step, make_train_step)
+from repro.train.steps import (StepConfig, decode_caches, init_train_state,
+                               make_decode_step, make_prefill_step,
+                               make_train_step)
 
 BATCH, SEQ = 2, 16
 
@@ -95,7 +95,7 @@ def test_decode_matches_prefill(arch_id):
     short.pop("labels", None)
     _, caches = jax.jit(prefill)(state.params, short)
     # grow caches to full seq for decode writes
-    caches = jax.tree.map(_pad_cache_to(cfg, 8), caches)
+    caches = decode_caches(cfg, caches, BATCH, 8, step_cfg.compute_dtype)
     logits = None
     for t in range(4, 8):
         tok = batch["tokens"][:, t:t + 1]
@@ -103,14 +103,3 @@ def test_decode_matches_prefill(arch_id):
     np.testing.assert_allclose(
         np.asarray(logits, np.float32),
         np.asarray(full_logits, np.float32), rtol=2e-2, atol=2e-2)
-
-
-def _pad_cache_to(cfg, max_seq):
-    def pad(t):
-        # KV caches have a sequence axis == axis 2 (layers, B, S, KV, hd)
-        if t.ndim == 5 and t.shape[2] < max_seq and \
-                t.shape[2] not in (cfg.ssm_state, 16):
-            pad_n = max_seq - t.shape[2]
-            return jnp.pad(t, [(0, 0), (0, 0), (0, pad_n), (0, 0), (0, 0)])
-        return t
-    return pad
