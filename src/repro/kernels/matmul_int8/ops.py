@@ -7,6 +7,7 @@ import jax.numpy as jnp
 
 from repro.kernels.matmul_int8.kernel import matmul_int8
 from repro.kernels.matmul_int8.ref import matmul_int8_ref, quantize_rowwise
+from repro.tracing import count, region
 
 
 def quantized_matmul(x: jax.Array, w: jax.Array, *,
@@ -19,11 +20,18 @@ def quantized_matmul(x: jax.Array, w: jax.Array, *,
     (core/tpu_bridge.py:select_matmul_blocks); defaults are MXU-aligned.
     The kernel compiles for the TPU; ``interpret=True`` runs it in the
     Pallas interpreter instead, which is how it runs on a CPU.
+
+    Regions (``repro.tracing``): ``matmul_int8.quantize``,
+    ``matmul_int8.pad`` (only where the blocks do not divide the dims) and
+    ``matmul_int8.kernel``; an eager call counts ``matmul_int8.calls``.
     """
     m, k = x.shape
     _, n = w.shape
-    x_q, x_s = quantize_rowwise(x, axis=1)
-    w_q, w_s = quantize_rowwise(w, axis=0)
+    if not isinstance(x, jax.core.Tracer):
+        count("matmul_int8.calls")
+    with region("matmul_int8.quantize"):
+        x_q, x_s = quantize_rowwise(x, axis=1)
+        w_q, w_s = quantize_rowwise(w, axis=0)
     if not use_kernel:
         return matmul_int8_ref(x_q, w_q, x_s, w_s, out_dtype)
     bm, bk, bn = block_shapes or default_blocks(m, k, n)
@@ -33,13 +41,15 @@ def quantized_matmul(x: jax.Array, w: jax.Array, *,
     # int32 accumulator, padded M/N rows/cols are sliced off the output.
     mp, kp, np_ = (-(-d // b) * b for d, b in ((m, bm), (k, bk), (n, bn)))
     if (mp, kp, np_) != (m, k, n):
-        x_q = jnp.pad(x_q, ((0, mp - m), (0, kp - k)))
-        w_q = jnp.pad(w_q, ((0, kp - k), (0, np_ - n)))
-        x_s = jnp.pad(x_s, (0, mp - m))
-        w_s = jnp.pad(w_s, (0, np_ - n))
-    out = matmul_int8(x_q, w_q, x_s, w_s, bm=bm, bk=bk, bn=bn,
-                      out_dtype=out_dtype, interpret=interpret)
-    return out[:m, :n]
+        with region("matmul_int8.pad"):
+            x_q = jnp.pad(x_q, ((0, mp - m), (0, kp - k)))
+            w_q = jnp.pad(w_q, ((0, kp - k), (0, np_ - n)))
+            x_s = jnp.pad(x_s, (0, mp - m))
+            w_s = jnp.pad(w_s, (0, np_ - n))
+    with region("matmul_int8.kernel"):
+        out = matmul_int8(x_q, w_q, x_s, w_s, bm=bm, bk=bk, bn=bn,
+                          out_dtype=out_dtype, interpret=interpret)
+        return out[:m, :n]
 
 
 def quantized_matmul_and_ref(x: jax.Array, w: jax.Array, *,

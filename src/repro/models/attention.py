@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import Identity, apply_rope, dense, init_dense
+from repro.tracing import region
 
 
 class KVCache(NamedTuple):
@@ -135,13 +136,16 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
+@region("attn")
 def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
               head_dim: int, rope_theta: float, causal: bool = True,
               positions: jax.Array | None = None,
               cache: KVCache | None = None,
               shard=Identity, use_flash: bool = False):
     """Returns (out, new_cache). Prefill: cache=None, full seq. Decode:
-    x is (B, 1, D) and cache holds past K/V."""
+    x is (B, 1, D) and cache holds past K/V. Named scopes
+    (``repro.tracing``): ``attn`` around it all, ``kv_update`` around the
+    decode's write of the new position into the cache."""
     b, l, _ = x.shape
     q = dense(params["wq"], x).reshape(b, l, n_heads, head_dim)
     k = dense(params["wk"], x).reshape(b, l, n_kv_heads, head_dim)
@@ -180,24 +184,26 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
                             dtype=jnp.float32)              # (B, S)
         quant = cache.k_scale is not None
         if quant:
-            qk, sk = quantize_kv(k)
-            qv, sv = quantize_kv(v)
-            ohq = oh[:, :, None, None]
-            k_cache = cache.k + (ohq * qk.astype(jnp.float32)).astype(
-                cache.k.dtype)
-            v_cache = cache.v + (ohq * qv.astype(jnp.float32)).astype(
-                cache.v.dtype)
-            k_scale = cache.k_scale + ohq * sk
-            v_scale = cache.v_scale + ohq * sv
+            with region("kv_update"):
+                qk, sk = quantize_kv(k)
+                qv, sv = quantize_kv(v)
+                ohq = oh[:, :, None, None]
+                k_cache = cache.k + (ohq * qk.astype(jnp.float32)).astype(
+                    cache.k.dtype)
+                v_cache = cache.v + (ohq * qv.astype(jnp.float32)).astype(
+                    cache.v.dtype)
+                k_scale = cache.k_scale + ohq * sk
+                v_scale = cache.v_scale + ohq * sv
             kf = _repeat_kv(dequantize_kv(k_cache, k_scale, x.dtype), rep)
             vf = _repeat_kv(dequantize_kv(v_cache, v_scale, x.dtype), rep)
             new_cache = KVCache(k=k_cache, v=v_cache,
                                 length=cache.length + 1,
                                 k_scale=k_scale, v_scale=v_scale)
         else:
-            ohq = oh[:, :, None, None].astype(cache.k.dtype)
-            k_cache = cache.k + ohq * k.astype(cache.k.dtype)
-            v_cache = cache.v + ohq * v.astype(cache.v.dtype)
+            with region("kv_update"):
+                ohq = oh[:, :, None, None].astype(cache.k.dtype)
+                k_cache = cache.k + ohq * k.astype(cache.k.dtype)
+                v_cache = cache.v + ohq * v.astype(cache.v.dtype)
             kf = _repeat_kv(k_cache, rep)
             vf = _repeat_kv(v_cache, rep)
             new_cache = KVCache(k=k_cache, v=v_cache,

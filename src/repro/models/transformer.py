@@ -33,6 +33,7 @@ from repro.models.layers import (Identity, embed, init_embedding, init_mlp,
 from repro.models.moe import init_moe, moe
 from repro.models.ssm import (SSMState, init_mamba2, init_ssm_state,
                               mamba2_block)
+from repro.tracing import region
 
 
 # Scan-over-layers unrolling. XLA's cost model counts a while-loop body
@@ -181,14 +182,15 @@ def _attn_block_apply(blk, x, cfg: ModelConfig, cache, *, causal, shard,
                           causal=False)
         x = x + dense(blk["xattn"]["wo"], o.reshape(b, l, -1))
     h = rms_norm(blk["ln2"], x, cfg.norm_eps)
-    if "moe" in blk:
-        mo, aux = moe(blk["moe"], h, n_experts=cfg.n_experts,
-                      top_k=cfg.top_k, gated=cfg.gated_mlp, shard=shard)
-        if "mlp" in blk:            # arctic dense residual
-            mo = mo + mlp(blk["mlp"], h, cfg.gated_mlp, shard)
-        x = x + mo
-    else:
-        x = x + mlp(blk["mlp"], h, cfg.gated_mlp, shard)
+    with region("mlp"):
+        if "moe" in blk:
+            mo, aux = moe(blk["moe"], h, n_experts=cfg.n_experts,
+                          top_k=cfg.top_k, gated=cfg.gated_mlp, shard=shard)
+            if "mlp" in blk:            # arctic dense residual
+                mo = mo + mlp(blk["mlp"], h, cfg.gated_mlp, shard)
+            x = x + mo
+        else:
+            x = x + mlp(blk["mlp"], h, cfg.gated_mlp, shard)
     return x, new_cache, aux, cross_kv
 
 
@@ -225,10 +227,13 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
             shard=Identity, use_flash: bool = False,
             remat: bool = False, compute_dtype=jnp.bfloat16) -> ForwardOut:
     """tokens: (B, L) int32. frontend_embeds: (B, S_front, D) for
-    audio/vision modalities (precomputed stub embeddings)."""
+    audio/vision modalities (precomputed stub embeddings). Named scopes
+    (``repro.tracing``): ``embed``, ``lm_head`` (final norm and unembed),
+    and per block ``attn`` and ``mlp``."""
     fam = cfg.family
     b, l = tokens.shape
-    x = embed(params["embed"], tokens, compute_dtype)
+    with region("embed"):
+        x = embed(params["embed"], tokens, compute_dtype)
     if frontend_embeds is not None and fam in ("vlm",) and mode != "decode":
         x = jnp.concatenate([frontend_embeds.astype(compute_dtype), x],
                             axis=1)
@@ -260,12 +265,13 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
     else:
         raise ValueError(fam)
 
-    x = rms_norm(params["ln_f"], x, cfg.norm_eps)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    if frontend_embeds is not None and fam == "vlm" and mode != "decode":
-        x = x[:, frontend_embeds.shape[1]:]
-    logits = unembed(table, x)
-    logits = shard("logits", logits)
+    with region("lm_head"):
+        x = rms_norm(params["ln_f"], x, cfg.norm_eps)
+        table = params["embed"] if cfg.tie_embeddings else params["unembed"]
+        if frontend_embeds is not None and fam == "vlm" and mode != "decode":
+            x = x[:, frontend_embeds.shape[1]:]
+        logits = unembed(table, x)
+        logits = shard("logits", logits)
     return ForwardOut(logits=logits, caches=new_caches, aux_loss=aux)
 
 
