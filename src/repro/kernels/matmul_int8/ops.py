@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -9,10 +11,15 @@ from repro.kernels.matmul_int8.kernel import matmul_int8
 from repro.kernels.matmul_int8.ref import matmul_int8_ref, quantize_rowwise
 from repro.tracing import count, region
 
+#: The host span around an eager call's one dispatch. It keeps the name
+#: the eager quantize had, for the readers of the profiler's spans
+#: (``bench/spans.py``), which pair one such span with each counted call.
+DISPATCH_SPAN = "matmul_int8.quantize"
+
 
 def quantized_matmul(x: jax.Array, w: jax.Array, *,
                      block_shapes: tuple[int, int, int] | None = None,
-                     use_kernel: bool = True, interpret: bool = False,
+                     interpret: bool = False,
                      out_dtype=jnp.bfloat16) -> jax.Array:
     """bf16/f32 (M,K) @ (K,N) via INT8 quantization (CIM-style W8A8).
 
@@ -21,20 +28,53 @@ def quantized_matmul(x: jax.Array, w: jax.Array, *,
     The kernel compiles for the TPU; ``interpret=True`` runs it in the
     Pallas interpreter instead, which is how it runs on a CPU.
 
-    Regions (``repro.tracing``): ``matmul_int8.quantize``,
-    ``matmul_int8.pad`` (only where the blocks do not divide the dims) and
-    ``matmul_int8.kernel``; an eager call counts ``matmul_int8.calls``.
+    Every call quantizes both operands and runs the kernel as one
+    compiled program per (shapes, blocks, ``out_dtype``, ``interpret``).
+    An eager call counts ``matmul_int8.calls`` and is one dispatch inside
+    the host span ``DISPATCH_SPAN``; the program's ops carry the scopes
+    ``matmul_int8.quantize``, ``matmul_int8.pad`` (only where the blocks
+    do not divide the dims) and ``matmul_int8.kernel`` (``repro.tracing``).
     """
-    m, k = x.shape
-    _, n = w.shape
     if not isinstance(x, jax.core.Tracer):
         count("matmul_int8.calls")
+    blocks = tuple(block_shapes or default_blocks(*x.shape, w.shape[1]))
+    with jax.profiler.TraceAnnotation(DISPATCH_SPAN):
+        return _quantize_then_matmul(x, w, blocks=blocks, out_dtype=out_dtype,
+                                     with_ref=False, interpret=interpret)
+
+
+def quantized_matmul_and_ref(x: jax.Array, w: jax.Array, *,
+                             block_shapes: tuple[int, int, int] | None = None,
+                             interpret: bool = False,
+                             out_dtype=jnp.float32
+                             ) -> tuple[jax.Array, jax.Array]:
+    """Kernel and pure-jnp oracle on identical quantized operands.
+
+    The measured-execution backend (`core/executor.py`) checks every kernel
+    invocation against its ``ref.py``; one program quantizes the operands
+    once and feeds both, which apply the scales in the same order, so the
+    two are bit-identical. Returns ``(kernel, ref)``."""
+    blocks = tuple(block_shapes or default_blocks(*x.shape, w.shape[1]))
+    return _quantize_then_matmul(x, w, blocks=blocks, out_dtype=out_dtype,
+                                 with_ref=True, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("blocks", "out_dtype",
+                                             "with_ref", "interpret"))
+def _quantize_then_matmul(x, w, *, blocks, out_dtype, with_ref, interpret):
+    """Quantize ``x`` by rows and ``w`` by columns, then the kernel on
+    ``blocks``, and with ``with_ref`` the ref as well: ``(kernel, ref)``.
+    Counts ``matmul_int8.traces`` once per trace, that is once per program
+    built.
+    """
+    count("matmul_int8.traces")
     with region("matmul_int8.quantize"):
         x_q, x_s = quantize_rowwise(x, axis=1)
         w_q, w_s = quantize_rowwise(w, axis=0)
-    if not use_kernel:
-        return matmul_int8_ref(x_q, w_q, x_s, w_s, out_dtype)
-    bm, bk, bn = block_shapes or default_blocks(m, k, n)
+    ref = matmul_int8_ref(x_q, w_q, x_s, w_s, out_dtype) if with_ref else None
+    m, k = x.shape
+    n = w.shape[1]
+    bm, bk, bn = blocks
     # The bridge may return MXU-aligned blocks that do not divide the dims
     # (dims without an aligned divisor are padded up): zero-pad the
     # quantized operands to block multiples — padded K contributes 0 to the
@@ -48,25 +88,8 @@ def quantized_matmul(x: jax.Array, w: jax.Array, *,
             w_s = jnp.pad(w_s, (0, np_ - n))
     with region("matmul_int8.kernel"):
         out = matmul_int8(x_q, w_q, x_s, w_s, bm=bm, bk=bk, bn=bn,
-                          out_dtype=out_dtype, interpret=interpret)
-        return out[:m, :n]
-
-
-def quantized_matmul_and_ref(x: jax.Array, w: jax.Array, *,
-                             block_shapes: tuple[int, int, int] | None = None,
-                             interpret: bool = False,
-                             out_dtype=jnp.float32
-                             ) -> tuple[jax.Array, jax.Array]:
-    """Kernel and pure-jnp oracle on identical quantized operands.
-
-    The measured-execution backend (`core/executor.py`) checks every kernel
-    invocation against its ``ref.py``; both paths quantize the same way and
-    apply the scales in the same order, so the two are bit-identical.
-    Returns ``(kernel, ref)``."""
-    out = quantized_matmul(x, w, block_shapes=block_shapes, use_kernel=True,
-                           interpret=interpret, out_dtype=out_dtype)
-    ref = quantized_matmul(x, w, use_kernel=False, out_dtype=out_dtype)
-    return out, ref
+                          out_dtype=out_dtype, interpret=interpret)[:m, :n]
+    return (out, ref) if with_ref else out
 
 
 def default_blocks(m: int, k: int, n: int) -> tuple[int, int, int]:

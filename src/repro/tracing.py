@@ -10,7 +10,10 @@ profiler running a region costs a few microseconds.
 
 ``count(name)`` adds to a plain in-memory counter and ``counters()``
 returns them all. A count made inside traced code runs once per trace,
-not once per call, so the program counts eagerly only. ``jax.compiles``
+not once per call, so the program counts calls eagerly only. One counter
+counts traces on purpose: ``matmul_int8.traces``, made inside the
+quantized matmul's jitted program, counts the programs built, one per
+new shape and blocks. ``jax.compiles``
 counts the executables JAX builds or loads from its persistent cache
 (the backend-compile event of ``jax.monitoring``), so that a caller can
 see a compile inside a window it times.

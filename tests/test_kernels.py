@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.kernels.flash_attention.kernel import flash_attention_bh
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
@@ -44,7 +45,7 @@ def test_quantized_matmul_close_to_fp(dtype):
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((64, 128)), dtype)
     w = jnp.asarray(rng.standard_normal((128, 96)) * 0.1, dtype)
-    out = quantized_matmul(x, w, use_kernel=True, interpret=True,
+    out = quantized_matmul(x, w, interpret=True,
                            out_dtype=jnp.float32)
     exact = (x.astype(jnp.float32) @ w.astype(jnp.float32))
     # int8 quantization error bound (~1%)
@@ -63,8 +64,7 @@ def test_quantized_matmul_bridge_padded_blocks():
     x = jnp.asarray(rng.standard_normal((512, 256)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((256, 360)) * 0.1, jnp.float32)
     out = quantized_matmul(x, w, block_shapes=(c.bm, c.bk, c.bn),
-                           use_kernel=True, interpret=True,
-                           out_dtype=jnp.float32)
+                           interpret=True, out_dtype=jnp.float32)
     assert out.shape == (512, 360)
     exact = x @ w
     rel = np.linalg.norm(np.asarray(out) - np.asarray(exact)) / \
@@ -84,8 +84,7 @@ def test_matmul_bridge_candidate_blocks_padded(m, k, n):
     x = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((k, n)) * 0.1, jnp.float32)
     out = quantized_matmul(x, w, block_shapes=(c.bm, c.bk, c.bn),
-                           use_kernel=True, interpret=True,
-                           out_dtype=jnp.float32)
+                           interpret=True, out_dtype=jnp.float32)
     assert out.shape == (m, n)
     exact = x @ w
     rel = np.linalg.norm(np.asarray(out) - np.asarray(exact)) / \
@@ -117,6 +116,58 @@ def test_matmul_mapping_derived_blocks():
                                         interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
+
+
+# (M, K, N, blocks): the LM head's shape class, one row against a vocabulary
+# that no bn divides (M, K and N padded), and one that needs no padding
+PROGRAM_CASES = {"lm_head_padded": (1, 64, 200, (8, 32, 128)),
+                 "aligned": (32, 64, 48, (16, 32, 16))}
+
+
+def _case_operands(m, k, n, seed):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.standard_normal((m, k)), jnp.float32),
+            jnp.asarray(rng.standard_normal((k, n)) * 0.1, jnp.float32))
+
+
+@pytest.mark.parametrize("case", sorted(PROGRAM_CASES))
+def test_quantized_matmul_is_the_eager_composition(case):
+    """The one compiled program gives, bit for bit, what the eager ops it
+    replaces give: quantize both operands, zero-pad to block multiples,
+    the kernel, then slice."""
+    m, k, n, (bm, bk, bn) = PROGRAM_CASES[case]
+    x, w = _case_operands(m, k, n, 10)
+    out = quantized_matmul(x, w, block_shapes=(bm, bk, bn), interpret=True,
+                           out_dtype=jnp.float32)
+    x_q, x_s = quantize_rowwise(x, axis=1)
+    w_q, w_s = quantize_rowwise(w, axis=0)
+    mp, kp, np_ = (-(-d // b) * b for d, b in ((m, bm), (k, bk), (n, bn)))
+    x_q = jnp.pad(x_q, ((0, mp - m), (0, kp - k)))
+    w_q = jnp.pad(w_q, ((0, kp - k), (0, np_ - n)))
+    x_s = jnp.pad(x_s, (0, mp - m))
+    w_s = jnp.pad(w_s, (0, np_ - n))
+    eager = matmul_int8(x_q, w_q, x_s, w_s, bm=bm, bk=bk, bn=bn,
+                        out_dtype=jnp.float32, interpret=True)[:m, :n]
+    assert out.shape == (m, n)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(eager))
+
+
+@pytest.mark.parametrize("case", sorted(PROGRAM_CASES))
+def test_quantized_matmul_traces_once_per_shape(case):
+    """A new shape builds one program; calling it again with the same
+    shapes traces and compiles nothing."""
+    m, k, n, blocks = PROGRAM_CASES[case]
+    k = 3 * k                   # a shape no other test calls
+    x, w = _case_operands(m, k, n, 11)
+    call = lambda: jax.block_until_ready(quantized_matmul(
+        x, w, block_shapes=blocks, interpret=True, out_dtype=jnp.float32))
+    got = lambda name: tracing.counters().get(name, 0)
+    t0 = got("matmul_int8.traces")
+    call()
+    t1, c1 = got("matmul_int8.traces"), got("jax.compiles")
+    call()
+    assert (t1 - t0, got("matmul_int8.traces") - t1,
+            got("jax.compiles") - c1) == (1, 0, 0)
 
 
 def test_quantize_roundtrip():

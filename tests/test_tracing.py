@@ -2,12 +2,14 @@
 
 Regions must reach both places a trace is read from: the host spans of a
 ``jax.profiler`` trace, for eager calls, and the ``op_name`` of the ops a
-jitted step compiles to. The flash prefill step is lowered for a TPU
-(Mosaic lowering needs no chip); its op names are the lowered module's
-locations, which become the compiled ops' ``op_name``.
+jitted step compiles to. The flash prefill step and the quantized
+matmul's program are lowered for a TPU (Mosaic lowering needs no chip);
+their op names are the lowered module's locations, which become the
+compiled ops' ``op_name``.
 """
 
 import dataclasses
+import functools
 import glob
 import re
 
@@ -18,7 +20,7 @@ import pytest
 from repro import tracing
 from repro.configs.registry import get_config
 from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.matmul_int8.ops import quantized_matmul
+from repro.kernels.matmul_int8.ops import DISPATCH_SPAN, quantized_matmul
 from repro.models.transformer import init_model
 from repro.train.steps import (StepConfig, init_caches, make_decode_step,
                                make_prefill_step)
@@ -34,22 +36,28 @@ def _host_spans(trace_dir) -> list[tuple[int, int, str]]:
 
 
 def test_regions_nest_as_host_spans(tmp_path):
-    # 40 x 48 at blocks of 32 pads M and K: all three regions run
-    x = jax.random.normal(jax.random.PRNGKey(0), (40, 48))
-    w = jax.random.normal(jax.random.PRNGKey(1), (48, 32))
-    quantized_matmul(x, w, block_shapes=(32, 32, 32), interpret=True)
+    """An eager call is one dispatch of a compiled program: one host span,
+    inside the caller's; the program's ops carry the three regions as
+    scopes. 40 x 200 @ 200 x 160 at blocks of (32, 128, 128) pads M, K and
+    N, so all three regions are in the program."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (40, 200))
+    w = jax.random.normal(jax.random.PRNGKey(1), (200, 160))
+    call = functools.partial(quantized_matmul, block_shapes=(32, 128, 128))
+    call(x, w, interpret=True)
     with jax.profiler.trace(str(tmp_path)):
         with tracing.region("outer"):
-            jax.block_until_ready(quantized_matmul(
-                x, w, block_shapes=(32, 32, 32), interpret=True))
+            jax.block_until_ready(call(x, w, interpret=True))
     spans = _host_spans(tmp_path)
-    assert [n for _, _, n in spans] == [
-        "outer", "matmul_int8.quantize", "matmul_int8.pad",
-        "matmul_int8.kernel"]
-    (o0, o1, _), inner = spans[0], spans[1:]
-    assert all(o0 <= s < e <= o1 for s, e, _ in inner)
-    # the three regions follow one another, none inside another
-    assert all(a[1] <= b[0] for a, b in zip(inner, inner[1:]))
+    assert [n for _, _, n in spans] == ["outer", DISPATCH_SPAN]
+    (o0, o1, _), (s, e, _) = spans
+    assert o0 <= s < e <= o1
+    lowered = jax.jit(call).trace(x, w).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text(debug_info=True)
+    assert "tpu_custom_call" in text
+    names = set(re.findall(r'loc\("([^"]*)"', text))
+    for scope in ("matmul_int8.quantize", "matmul_int8.pad",
+                  "matmul_int8.kernel"):
+        assert _under(names, scope), scope
 
 
 def test_counters_count_eager_calls_only():
