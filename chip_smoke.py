@@ -19,7 +19,9 @@ One process runs every phase and owns the chip; the MIP solves stay in it
            kernel inside the model; its last-position logits must match
            the plain prefill's within ``FLASH_TOL``.
 
-``--chips 4`` runs only the sharded phase: glm4-9b at full depth, its
+``--chips 4`` runs only the sharded phase: glm4-9b at full depth (QKV
+bias, the half-width interleaved rotary, 2 KV heads whose cache is split
+on its sequence axis over the 4 chips), its
 parameters made shard by shard on a (data=1, model=4) mesh, prefills
 1 x 2048 tokens and decodes 8; then a 2-layer cut at the same widths runs
 sharded and on one device, and the two last-position logits must agree

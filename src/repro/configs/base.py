@@ -46,6 +46,9 @@ class ModelConfig:
     frontend_seq: int = 0             # precomputed frame/patch positions
     # --- misc ---
     rope_theta: float = 10_000.0
+    rope_dim: int = 0                 # rotated dims of a head (0 -> all)
+    rope_interleaved: bool = False    # pairs (2i, 2i+1), not rotate-half
+    qkv_bias: bool = False            # bias on the q/k/v projections only
     gated_mlp: bool = True
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -75,6 +78,8 @@ class ModelConfig:
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + \
             self.n_heads * hd * d
+        if self.qkv_bias:
+            attn += hd * (self.n_heads + 2 * self.n_kv_heads)
         mlp_mult = 3 if self.gated_mlp else 2
         dense_mlp = mlp_mult * d * self.d_ff if self.d_ff else 0
         moe = 0
@@ -120,6 +125,9 @@ class ModelConfig:
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 4) if self.n_kv_heads else 0,
             head_dim=16,
+            # the same share of the (now 16-wide) head rotates
+            rope_dim=self.rope_dim * 16 // self.resolved_head_dim
+            if self.rope_dim else 0,
             d_ff=128 if self.d_ff else 0,
             vocab_size=256,
             n_experts=min(self.n_experts, 8),

@@ -1,5 +1,12 @@
 """GQA attention with RoPE, causal masking, KV caching and an optional
-flash-attention Pallas kernel path (repro/kernels/flash_attention)."""
+flash-attention Pallas kernel path (repro/kernels/flash_attention).
+
+Decode with grouped heads (``n_kv_heads < n_heads``) attends each KV head
+once for the query heads that share it, without a repeated cache. Where
+the distribution layer splits the cache's sequence axis over devices
+(``layers.kv_splits(shard) > 1``), every device attends its own part of
+the positions for every query head, and the parts' softmax statistics
+are then combined (``grouped_decode_attention``)."""
 
 from __future__ import annotations
 
@@ -9,7 +16,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import Identity, apply_rope, dense, init_dense
+from repro.models.layers import (Identity, apply_rope, dense, init_dense,
+                                 kv_splits)
 from repro.tracing import region
 
 
@@ -29,12 +37,15 @@ KV_QUANT = False          # int8 KV cache (capacity optimization)
 
 
 def init_attention(key, d_model: int, n_heads: int, n_kv_heads: int,
-                   head_dim: int, dtype=jnp.float32) -> dict:
+                   head_dim: int, dtype=jnp.float32,
+                   qkv_bias: bool = False) -> dict:
     kq, kk, kv, ko = jax.random.split(key, 4)
     return {
-        "wq": init_dense(kq, d_model, n_heads * head_dim, dtype),
-        "wk": init_dense(kk, d_model, n_kv_heads * head_dim, dtype),
-        "wv": init_dense(kv, d_model, n_kv_heads * head_dim, dtype),
+        "wq": init_dense(kq, d_model, n_heads * head_dim, dtype, qkv_bias),
+        "wk": init_dense(kk, d_model, n_kv_heads * head_dim, dtype,
+                         qkv_bias),
+        "wv": init_dense(kv, d_model, n_kv_heads * head_dim, dtype,
+                         qkv_bias),
         "wo": init_dense(ko, n_heads * head_dim, d_model, dtype),
     }
 
@@ -84,7 +95,8 @@ def dot_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bsz, lq, h, hd = q.shape
     lk = k.shape[1]
     block_k = min(block_k, lk)
-    assert lk % block_k == 0
+    while lk % block_k:                 # the largest block that divides
+        block_k -= 1
     nb = lk // block_k
     scale = 1.0 / math.sqrt(hd)
     kb = k.reshape(bsz, nb, block_k, h, hd)
@@ -124,6 +136,53 @@ def dot_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
+def grouped_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                             kv_length: jax.Array, *, splits: int = 1,
+                             shard=Identity) -> jax.Array:
+    """One query position against a cache, query heads grouped by the KV
+    head they share. q: (B, 1, H, hd); k, v: (B, S, KV, hd); positions at
+    and past ``kv_length`` (B,) are masked. Returns (B, 1, H, hd).
+
+    The cache's positions are taken as ``splits`` equal parts (the parts
+    the distribution layer keeps on separate devices). Named scopes
+    (``repro.tracing``): ``kv_attend`` around the attention over each
+    part, giving its running max, sum and weighted values, and, with more
+    than one part, ``kv_combine`` around their combination."""
+    b, _, h, hd = q.shape
+    s_len, g = k.shape[1], k.shape[2]
+    r, n = h // g, splits
+    if n > 1:
+        q = shard("kv_q", q)                     # every head on every part
+    qg = q.reshape(b, g, r, hd)
+    kb = shard("kv_split", k.reshape(b, n, s_len // n, g, hd))
+    vb = shard("kv_split", v.reshape(b, n, s_len // n, g, hd))
+    neg = jnp.float32(-1e30)
+    with region("kv_attend"):
+        scores = jnp.einsum("bgrd,bnsgd->bngrs", qg, kb,
+                            preferred_element_type=jnp.float32)
+        scores = scores * (1.0 / math.sqrt(hd))
+        kpos = jnp.arange(s_len).reshape(n, s_len // n)
+        valid = kpos[None] < kv_length[:, None, None]          # (B, n, s)
+        scores = jnp.where(valid[:, :, None, None, :], scores, neg)
+        m = jnp.max(scores, axis=-1)                           # (B,n,g,r)
+        p = jnp.exp(scores - m[..., None])
+        s_sum = jnp.sum(p, axis=-1)
+        o = jnp.einsum("bngrs,bnsgd->bngrd", p.astype(q.dtype), vb,
+                       preferred_element_type=jnp.float32)
+        m, s_sum = shard("kv_split", m), shard("kv_split", s_sum)
+        o = shard("kv_split", o)
+    if n == 1:
+        s_sum, o = s_sum[:, 0], o[:, 0]
+    else:
+        with region("kv_combine"):
+            top = jnp.max(m, axis=1, keepdims=True)
+            alpha = jnp.exp(m - top)                # 0 for a part all masked
+            s_sum = jnp.sum(s_sum * alpha, axis=1)
+            o = jnp.sum(o * alpha[..., None], axis=1)
+    out = o / s_sum[..., None]
+    return out.astype(q.dtype).reshape(b, 1, h, hd)
+
+
 def quantize_kv(x: jax.Array):
     """Per-(position, head) symmetric int8 KV quantization."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
@@ -141,22 +200,28 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
               head_dim: int, rope_theta: float, causal: bool = True,
               positions: jax.Array | None = None,
               cache: KVCache | None = None,
-              shard=Identity, use_flash: bool = False):
+              shard=Identity, use_flash: bool = False,
+              rope_dim: int = 0, rope_interleaved: bool = False):
     """Returns (out, new_cache). Prefill: cache=None, full seq. Decode:
     x is (B, 1, D) and cache holds past K/V. Named scopes
-    (``repro.tracing``): ``attn`` around it all, ``kv_update`` around the
-    decode's write of the new position into the cache."""
+    (``repro.tracing``): ``attn`` around it all, ``rope`` around the
+    rotary, ``kv_update`` around the decode's write of the new position
+    into the cache, and ``grouped_decode_attention``'s."""
     b, l, _ = x.shape
     q = dense(params["wq"], x).reshape(b, l, n_heads, head_dim)
     k = dense(params["wk"], x).reshape(b, l, n_kv_heads, head_dim)
     v = dense(params["wv"], x).reshape(b, l, n_kv_heads, head_dim)
     q = shard("attn_q", q)
     rep = n_heads // n_kv_heads
+
+    def rope(t, pos):
+        return apply_rope(t, pos, rope_theta, rope_dim, rope_interleaved)
+
     if cache is None:
         pos = positions if positions is not None else jnp.arange(l)
         if rope_theta:
-            q = apply_rope(q, pos, rope_theta)
-            k = apply_rope(k, pos, rope_theta)
+            with region("rope"):
+                q, k = rope(q, pos), rope(k, pos)
         kf, vf = _repeat_kv(k, rep), _repeat_kv(v, rep)
         if use_flash and causal and l >= 512:
             from repro.kernels.flash_attention.ops import flash_attention
@@ -178,8 +243,8 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
         # single-token decode against the cache
         pos = cache.length                                  # (B,)
         if rope_theta:
-            q = apply_rope(q, pos[:, None], rope_theta)
-            k = apply_rope(k, pos[:, None], rope_theta)
+            with region("rope"):
+                q, k = rope(q, pos[:, None]), rope(k, pos[:, None])
         oh = jax.nn.one_hot(cache.length, cache.k.shape[1],
                             dtype=jnp.float32)              # (B, S)
         quant = cache.k_scale is not None
@@ -194,8 +259,8 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
                     cache.v.dtype)
                 k_scale = cache.k_scale + ohq * sk
                 v_scale = cache.v_scale + ohq * sv
-            kf = _repeat_kv(dequantize_kv(k_cache, k_scale, x.dtype), rep)
-            vf = _repeat_kv(dequantize_kv(v_cache, v_scale, x.dtype), rep)
+            kf = dequantize_kv(k_cache, k_scale, x.dtype)
+            vf = dequantize_kv(v_cache, v_scale, x.dtype)
             new_cache = KVCache(k=k_cache, v=v_cache,
                                 length=cache.length + 1,
                                 k_scale=k_scale, v_scale=v_scale)
@@ -204,12 +269,16 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
                 ohq = oh[:, :, None, None].astype(cache.k.dtype)
                 k_cache = cache.k + ohq * k.astype(cache.k.dtype)
                 v_cache = cache.v + ohq * v.astype(cache.v.dtype)
-            kf = _repeat_kv(k_cache, rep)
-            vf = _repeat_kv(v_cache, rep)
+            kf, vf = k_cache, v_cache
             new_cache = KVCache(k=k_cache, v=v_cache,
                                 length=cache.length + 1)
-        out = dot_attention(q, kf, vf, causal=False,
-                            kv_length=cache.length + 1)
+        splits = kv_splits(shard)
+        if rep == 1 and splits == 1:
+            out = dot_attention(q, kf, vf, causal=False,
+                                kv_length=cache.length + 1)
+        else:
+            out = grouped_decode_attention(q, kf, vf, cache.length + 1,
+                                           splits=splits, shard=shard)
     out = shard("attn_out", out)
     out = out.reshape(b, l, n_heads * head_dim)
     return dense(params["wo"], out), new_cache

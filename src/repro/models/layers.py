@@ -17,6 +17,13 @@ import jax.numpy as jnp
 Identity = lambda name, x: x
 
 
+def kv_splits(shard) -> int:
+    """Into how many equal parts the distribution layer splits a KV
+    cache's sequence axis across devices (a ``shard`` callback's
+    ``kv_splits``); 1 for a callback that splits nothing."""
+    return getattr(shard, "kv_splits", 1)
+
+
 def truncated_normal(key, shape, std, dtype=jnp.float32):
     return std * jax.random.truncated_normal(key, -2.0, 2.0, shape,
                                              jnp.float32).astype(dtype)
@@ -56,13 +63,20 @@ def layer_norm(params: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
 # Dense / MLP
 # ---------------------------------------------------------------------------
 
-def init_dense(key, d_in: int, d_out: int, dtype=jnp.float32) -> dict:
+def init_dense(key, d_in: int, d_out: int, dtype=jnp.float32,
+               bias: bool = False) -> dict:
     std = 1.0 / math.sqrt(d_in)
-    return {"w": truncated_normal(key, (d_in, d_out), std, dtype)}
+    p = {"w": truncated_normal(key, (d_in, d_out), std, dtype)}
+    if bias:
+        p["b"] = jnp.zeros((d_out,), dtype)
+    return p
 
 
 def dense(params: dict, x: jax.Array) -> jax.Array:
-    return x @ params["w"].astype(x.dtype)
+    y = x @ params["w"].astype(x.dtype)
+    if "b" in params:
+        y = y + params["b"].astype(x.dtype)
+    return y
 
 
 def init_mlp(key, d_model: int, d_ff: int, gated: bool,
@@ -94,17 +108,33 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
                                        dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               rope_dim: int = 0, interleaved: bool = False) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq).
+
+    Rotates the first ``rope_dim`` dims of each head (0: all of them) at
+    frequencies ``theta ** (-2i / rope_dim)`` and passes the rest
+    through. Pair i is (i, i + rope_dim/2) (rotate-half) or, with
+    ``interleaved``, the adjacent (2i, 2i+1)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # (.., L, hd/2)
+    rd = rope_dim or hd
+    freqs = rope_freqs(rd, theta)
+    angles = positions[..., None].astype(jnp.float32) * freqs  # (.., L, rd/2)
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                          axis=-1)
+    xf = x.astype(jnp.float32)
+    xr = xf if rd == hd else xf[..., :rd]
+    if interleaved:
+        pairs = xr.reshape(xr.shape[:-1] + (rd // 2, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                        axis=-1).reshape(xr.shape)
+    else:
+        x1, x2 = jnp.split(xr, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                              axis=-1)
+    if rd < hd:
+        out = jnp.concatenate([out, xf[..., rd:]], axis=-1)
     return out.astype(x.dtype)
 
 

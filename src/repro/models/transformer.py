@@ -62,7 +62,7 @@ def _init_attn_block(key, cfg: ModelConfig, dtype) -> dict:
     p = {
         "ln1": init_rmsnorm(cfg.d_model),
         "attn": init_attention(k1, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
-                               hd, dtype),
+                               hd, dtype, cfg.qkv_bias),
         "ln2": init_rmsnorm(cfg.d_model),
     }
     if cfg.n_experts and cfg.family == "moe":
@@ -93,7 +93,7 @@ def _init_cross_block(key, cfg: ModelConfig, dtype) -> dict:
     hd = cfg.resolved_head_dim
     p["ln_x"] = init_rmsnorm(cfg.d_model)
     p["xattn"] = init_attention(k, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
-                                hd, dtype)
+                                hd, dtype, cfg.qkv_bias)
     return p
 
 
@@ -155,7 +155,8 @@ def _attn_block_apply(blk, x, cfg: ModelConfig, cache, *, causal, shard,
     attn_out, new_cache = attention(
         blk["attn"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         head_dim=hd, rope_theta=cfg.rope_theta, causal=causal, cache=cache,
-        shard=shard, use_flash=use_flash)
+        shard=shard, use_flash=use_flash, rope_dim=cfg.rope_dim,
+        rope_interleaved=cfg.rope_interleaved)
     x = x + attn_out
     aux = jnp.zeros((), jnp.float32)
     cross_kv = None

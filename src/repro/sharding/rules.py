@@ -10,8 +10,12 @@ Mesh axes:
 
 Strategy per tensor class (see DESIGN.md §Sharding rules):
   * dense kernels (d_in, d_out): P("data", "model") — FSDP x TP
-  * attention projections: TP over heads when divisible, else fully-FSDP
-    (P(("data","model"), None)) with replicated attention compute
+  * attention projections: TP over heads when divisible; K/V projections
+    with fewer KV heads than the model axis are replicated over it
+    (P("data", None)): every model shard computes the few K/V heads
+  * KV cache: heads on the model axis when divisible, else its sequence
+    axis; decode then attends each device's positions for every query
+    head and combines the parts (logical "kv_q", "kv_split")
   * MoE experts (E, d, f): EP P("model", "data", None) when E % model == 0,
     else TP inside experts P(None, "data", "model")
   * embeddings (V, d): P("model", "data") — vocab-sharded
@@ -77,9 +81,11 @@ class ShardingPlan:
         if "router" in name:
             return wrap(data, None)
         # attention projections
-        if any(k in name for k in ("wq", "wk", "wv")):
-            tp_ok = self.attn_tp if "wq" in name else self.kv_tp
-            return wrap(data, model) if tp_ok else wrap((data, model), None)
+        if "wq" in name:
+            return wrap(data, model) if self.attn_tp \
+                else wrap((data, model), None)
+        if any(k in name for k in ("wk", "wv")):
+            return wrap(data, model) if self.kv_tp else wrap(data, None)
         if "wo" in name:
             return wrap(model, data) if self.attn_tp \
                 else wrap((data, model), None)
@@ -143,7 +149,13 @@ class ShardingPlan:
             "moe_expert_in": P(model if self.moe_ep else None, None, None),
             "moe_expert_out": P(model if self.moe_ep else None, None, None),
             "ssm_x": P(batch, seq, model if self._ssm_tp() else None, None),
+            "kv_q": P(batch, seq, None, None),
+            "kv_split": P(batch, self._kv_seq_axes()),
         }.get(logical, P())
+
+    def _kv_seq_axes(self):
+        """The mesh axes a KV cache's sequence axis is split over."""
+        return self.cache_spec("kv")[2]
 
     def shard_fn(self):
         def fn(logical: str, x):
@@ -153,6 +165,10 @@ class ShardingPlan:
                     x, NamedSharding(self.mesh, spec))
             except (ValueError, KeyError):
                 return x
+        axes = self._kv_seq_axes()
+        axes = () if axes is None else \
+            (axes if isinstance(axes, tuple) else (axes,))
+        fn.kv_splits = int(np.prod([self.mesh.shape[a] for a in axes]))
         return fn
 
     # ---- KV cache / SSM state specs -----------------------------------------
