@@ -56,7 +56,7 @@ def decode_demo():
     params = state.params
     batch, prompt_len, gen_len = 4, 12, 20
     # The KV cache needs exactly prompt + generated positions: the decode
-    # step appends one token per call via a one-hot(length) scatter, which
+    # step writes one position per call at the row's length, and
     # silently drops any write past the padded length — so an undersized
     # max_seq truncates the cache while the token loop keeps "working".
     max_seq = prompt_len + gen_len

@@ -11,6 +11,7 @@ are then combined (``grouped_decode_attention``)."""
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -183,6 +184,38 @@ def grouped_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.astype(q.dtype).reshape(b, 1, h, hd)
 
 
+#: Positions a decode writes as one window: the TPU's lane width. The
+#: device keeps a cache with its sequence axis minor (lanes), where a
+#: write narrower than a lane tile, or a scatter, makes XLA hold the
+#: whole cache in another layout and copy it in and out of the step.
+LANES = 128
+
+
+def write_position(buf: jax.Array, new: jax.Array, length: jax.Array,
+                   layer: jax.Array | None = None, *,
+                   window: int) -> jax.Array:
+    """``buf`` with ``new[b]`` written at position ``length[b]`` of row b.
+
+    buf: (B, S, KV, X), or (L, B, S, KV, X) with ``layer`` the layer to
+    write; new: (B, KV, X). Each row reads the ``window`` positions
+    (``window`` divides S) that hold its position, selects the new value
+    into the one position and writes the window back where it was read,
+    so a donated ``buf`` is updated in place. A position outside [0, S)
+    matches no position of its window: that write is dropped."""
+    lead = () if layer is None else (layer,)
+    s_len = buf.shape[-3]
+    start = jnp.clip(length // window * window, 0, s_len - window)
+    size = (1,) * len(lead) + (1, window) + buf.shape[-2:]
+    for row in range(buf.shape[len(lead)]):
+        at = lead + (row, start[row], 0, 0)
+        old = jax.lax.dynamic_slice(buf, at, size)
+        hit = (start[row] + jnp.arange(window) == length[row])[:, None, None]
+        val = jnp.where(hit, new[row].astype(buf.dtype), old.reshape(
+            old.shape[-3:]))
+        buf = jax.lax.dynamic_update_slice(buf, val.reshape(size), at)
+    return buf
+
+
 def quantize_kv(x: jax.Array):
     """Per-(position, head) symmetric int8 KV quantization."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
@@ -199,14 +232,22 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
 def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
               head_dim: int, rope_theta: float, causal: bool = True,
               positions: jax.Array | None = None,
-              cache: KVCache | None = None,
+              cache: KVCache | None = None, layer: jax.Array | None = None,
               shard=Identity, use_flash: bool = False,
               rope_dim: int = 0, rope_interleaved: bool = False):
-    """Returns (out, new_cache). Prefill: cache=None, full seq. Decode:
-    x is (B, 1, D) and cache holds past K/V. Named scopes
-    (``repro.tracing``): ``attn`` around it all, ``rope`` around the
-    rotary, ``kv_update`` around the decode's write of the new position
-    into the cache, and ``grouped_decode_attention``'s."""
+    """Returns (out, new_cache). Prefill: cache=None, full seq; new_cache
+    holds the sequence's K/V. Decode: x is (B, 1, D) and cache holds past
+    K/V, either one layer's (``layer`` None) or the whole layer stack
+    (leaves (L, B, ...), ``layer`` the index of this one). The decode
+    writes the new K and V (and, int8, their scales) at each row's
+    ``length`` (``write_position``; on each device's part where the
+    cache's sequence axis is split), in place where the caller donated
+    the cache, and advances that layer's ``length``; a write past the
+    cache's end is dropped while ``length`` still rises. It then attends
+    over the positions before ``length + 1``, and returns the cache it
+    was given with those writes. Named scopes (``repro.tracing``): ``attn`` around it all,
+    ``rope`` around the rotary, ``kv_update`` around the cache write, and
+    ``grouped_decode_attention``'s."""
     b, l, _ = x.shape
     q = dense(params["wq"], x).reshape(b, l, n_heads, head_dim)
     k = dense(params["wk"], x).reshape(b, l, n_kv_heads, head_dim)
@@ -240,44 +281,49 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
             new_cache = KVCache(k=k, v=v,
                                 length=jnp.full((b,), l, jnp.int32))
     else:
-        # single-token decode against the cache
-        pos = cache.length                                  # (B,)
+        # single-token decode against the cache: write the new position,
+        # then attend over the positions before length + 1
+        length = cache.length if layer is None else cache.length[layer]
         if rope_theta:
             with region("rope"):
-                q, k = rope(q, pos[:, None]), rope(k, pos[:, None])
-        oh = jax.nn.one_hot(cache.length, cache.k.shape[1],
-                            dtype=jnp.float32)              # (B, S)
-        quant = cache.k_scale is not None
-        if quant:
-            with region("kv_update"):
+                q, k = rope(q, length[:, None]), rope(k, length[:, None])
+        splits = kv_splits(shard)
+        put = partial(write_position,
+                      window=math.gcd(cache.k.shape[-3] // splits, LANES))
+        lead = () if layer is None else (layer,)
+
+        def write(buf, new):
+            if splits > 1:            # each device writes its own part
+                return shard.kv_local(put, buf, new[:, 0], length, *lead)
+            return put(buf, new[:, 0], length, *lead)
+
+        with region("kv_update"):
+            if cache.k_scale is None:
+                new_cache = cache._replace(k=write(cache.k, k),
+                                           v=write(cache.v, v))
+            else:
                 qk, sk = quantize_kv(k)
                 qv, sv = quantize_kv(v)
-                ohq = oh[:, :, None, None]
-                k_cache = cache.k + (ohq * qk.astype(jnp.float32)).astype(
-                    cache.k.dtype)
-                v_cache = cache.v + (ohq * qv.astype(jnp.float32)).astype(
-                    cache.v.dtype)
-                k_scale = cache.k_scale + ohq * sk
-                v_scale = cache.v_scale + ohq * sv
-            kf = dequantize_kv(k_cache, k_scale, x.dtype)
-            vf = dequantize_kv(v_cache, v_scale, x.dtype)
-            new_cache = KVCache(k=k_cache, v=v_cache,
-                                length=cache.length + 1,
-                                k_scale=k_scale, v_scale=v_scale)
-        else:
-            with region("kv_update"):
-                ohq = oh[:, :, None, None].astype(cache.k.dtype)
-                k_cache = cache.k + ohq * k.astype(cache.k.dtype)
-                v_cache = cache.v + ohq * v.astype(cache.v.dtype)
-            kf, vf = k_cache, v_cache
-            new_cache = KVCache(k=k_cache, v=v_cache,
-                                length=cache.length + 1)
-        splits = kv_splits(shard)
+                new_cache = cache._replace(
+                    k=write(cache.k, qk), v=write(cache.v, qv),
+                    k_scale=write(cache.k_scale, sk),
+                    v_scale=write(cache.v_scale, sv))
+            new_cache = new_cache._replace(
+                length=cache.length + 1 if layer is None
+                else cache.length.at[layer].add(1))
+
+        def this_layer(t):
+            return t if layer is None else t[layer]
+
+        kf, vf = this_layer(new_cache.k), this_layer(new_cache.v)
+        if cache.k_scale is not None:
+            kf = dequantize_kv(kf, this_layer(new_cache.k_scale), x.dtype)
+            vf = dequantize_kv(vf, this_layer(new_cache.v_scale), x.dtype)
         if rep == 1 and splits == 1:
             out = dot_attention(q, kf, vf, causal=False,
-                                kv_length=cache.length + 1)
+                                kv_length=length + 1)
         else:
-            out = grouped_decode_attention(q, kf, vf, cache.length + 1,
+            out = grouped_decode_attention(q, kf, vf, length + 1,
                                            splits=splits, shard=shard)
     out = shard("attn_out", out)
     out = out.reshape(b, l, n_heads * head_dim)

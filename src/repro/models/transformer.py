@@ -12,8 +12,11 @@ One ``init_model`` / ``forward`` pair covers:
 Layers are stacked and driven by ``jax.lax.scan`` (small HLO, fast 512-way
 compile); training wraps the block in ``jax.checkpoint``. Modes:
   "train"   tokens -> logits                  (full seq, causal)
-  "prefill" tokens -> logits + caches
+  "prefill" tokens -> logits + caches         (the scan's ys)
   "decode"  one token + caches -> logits + caches
+Decode of the attention stacks carries the layer-stacked KV cache through
+the scan and writes each layer's one new position into it in place; no
+layer's cache is sliced out, rebuilt or stacked back.
 """
 
 from __future__ import annotations
@@ -149,13 +152,13 @@ class ForwardOut:
 
 
 def _attn_block_apply(blk, x, cfg: ModelConfig, cache, *, causal, shard,
-                      use_flash, memory=None, mem_cross_kv=None):
+                      use_flash, memory=None, mem_cross_kv=None, layer=None):
     hd = cfg.resolved_head_dim
     h = rms_norm(blk["ln1"], x, cfg.norm_eps)
     attn_out, new_cache = attention(
         blk["attn"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         head_dim=hd, rope_theta=cfg.rope_theta, causal=causal, cache=cache,
-        shard=shard, use_flash=use_flash, rope_dim=cfg.rope_dim,
+        layer=layer, shard=shard, use_flash=use_flash, rope_dim=cfg.rope_dim,
         rope_interleaved=cfg.rope_interleaved)
     x = x + attn_out
     aux = jnp.zeros((), jnp.float32)
@@ -197,20 +200,27 @@ def _attn_block_apply(blk, x, cfg: ModelConfig, cache, *, causal, shard,
 
 def _scan_attn_layers(params_stack, x, cfg, caches, *, causal, shard,
                       use_flash, remat):
-    """caches: stacked per-layer KVCache for decode, or None (train /
-    prefill / encode — prefill collects fresh caches from the scan ys)."""
+    """caches: the stacked per-layer KVCache for decode, or None (train /
+    prefill / encode: prefill collects fresh caches from the scan's ys).
+    Decode carries the stack through the scan: each layer writes its new
+    position into it in place (``attention``'s ``layer``), so the scan's
+    xs are the layer parameters and their index, and its ys are empty."""
     def body(carry, layer_in):
-        x, aux = carry
-        blk, cache = layer_in
-        x, new_cache, aux_l, _ = _attn_block_apply(
-            blk, x, cfg, cache, causal=causal, shard=shard,
+        x, aux, stack = carry
+        blk, layer = layer_in
+        x, cache, aux_l, _ = _attn_block_apply(
+            blk, x, cfg, stack, layer=layer, causal=causal, shard=shard,
             use_flash=use_flash)
-        return (x, aux + aux_l), new_cache
+        if stack is None:
+            return (x, aux + aux_l, None), cache
+        return (x, aux + aux_l, cache), None
 
     fn = jax.checkpoint(body) if remat else body
-    (x, aux), new_caches = _scan(
-        fn, (x, jnp.zeros((), jnp.float32)), (params_stack, caches))
-    return x, aux, new_caches
+    n = jax.tree.leaves(params_stack)[0].shape[0]
+    (x, aux, stack), ys = _scan(
+        fn, (x, jnp.zeros((), jnp.float32), caches),
+        (params_stack, jnp.arange(n)))
+    return x, aux, ys if caches is None else stack
 
 
 def _dummy_caches(n_layers, batch, max_seq, cfg, dtype):

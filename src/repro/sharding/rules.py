@@ -165,11 +165,36 @@ class ShardingPlan:
                     x, NamedSharding(self.mesh, spec))
             except (ValueError, KeyError):
                 return x
+        fn.kv_splits = self._kv_splits()
+        fn.kv_local = self.kv_local
+        return fn
+
+    def _kv_splits(self) -> int:
         axes = self._kv_seq_axes()
         axes = () if axes is None else \
             (axes if isinstance(axes, tuple) else (axes,))
-        fn.kv_splits = int(np.prod([self.mesh.shape[a] for a in axes]))
-        return fn
+        return int(np.prod([self.mesh.shape[a] for a in axes]))
+
+    def kv_local(self, write, buf, new, length, *extra):
+        """``write(part, new, length - first, *extra)`` on each device's
+        part of ``buf``, a KV cache leaf split on its sequence axis (one
+        layer's, or stacked over layers), ``first`` being the part's first
+        position: a write lands on the one device that holds its
+        position, and no device sees another's part. ``extra`` is
+        replicated."""
+        spec = self.cache_spec("kv")
+        spec = P(*spec[len(spec) - buf.ndim:])
+        batch, seq, heads, minor = spec[-4:]
+        part = buf.shape[-3] // self._kv_splits()
+
+        def local(buf, new, length, *extra):
+            first = jax.lax.axis_index(seq) * part
+            return write(buf, new, length - first, *extra)
+
+        return jax.shard_map(
+            local, mesh=self.mesh, out_specs=spec,
+            in_specs=(spec, P(batch, heads, minor), P(batch))
+            + (P(),) * len(extra))(buf, new, length, *extra)
 
     # ---- KV cache / SSM state specs -----------------------------------------
     def cache_spec(self, kind: str) -> P:

@@ -228,7 +228,7 @@ def decode_caches(cfg: ModelConfig, prefilled, batch: int, max_seq: int,
     """``init_caches`` for ``max_seq`` positions holding a prefill's caches.
 
     The prefill step returns caches as long as its prompt; the decode step
-    appends one position per call through a one-hot(length) scatter, which
+    writes one position per call at each row's length, in place, and
     drops a write past the cache's end without a word. Every prefilled
     leaf is written at the origin of its zero-initialized counterpart (and
     keeps the prefill's dtype, which is what decode returns), so only the

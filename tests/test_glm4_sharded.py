@@ -23,6 +23,10 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: Batch, prompt and turn of the cut: 64 positions, 16 a device.
 B, PROMPT, GEN = 4, 60, 4
+MAX_SEQ = PROMPT + GEN
+#: A prompt whose decode writes positions 14, 15 and 16: from the first
+#: device's part of the sequence into the second's.
+CROSSING = 14
 #: The same float32 arithmetic as on one device, with partial sums
 #: reduced across devices in another order: about 1e-6 of the logits.
 TOL = 1e-5
@@ -31,9 +35,11 @@ COLLECTIVE = re.compile(
     r"(-start)?\(")
 
 
-def sharded_vs_one_device() -> dict:
-    """Prefill and decode on one device and on a (1, 4) mesh; their
-    logits' largest gap, and the compiled sharded decode step's text."""
+def sharded_vs_one_device(prompt: int = PROMPT) -> dict:
+    """Prefill ``prompt`` tokens and decode to ``prompt + GEN`` on one
+    device and on a (1, 4) mesh, in caches of ``MAX_SEQ`` positions; the
+    largest gaps of their logits and their final caches, and the compiled
+    sharded decode step's text."""
     import dataclasses
 
     import jax
@@ -52,63 +58,81 @@ def sharded_vs_one_device() -> dict:
     cfg = dataclasses.replace(get_config("glm4-9b").reduced(), n_heads=8,
                               n_kv_heads=2)
     mesh = make_host_mesh()
-    plan = make_plan(mesh, cfg, ShapeSpec("serve", PROMPT + GEN, B,
-                                          "decode"))
+    plan = make_plan(mesh, cfg, ShapeSpec("serve", MAX_SEQ, B, "decode"))
     shard = plan.shard_fn()
     step_cfg = StepConfig(remat=False, compute_dtype=jnp.float32)
     one = init_model(jax.random.PRNGKey(0), cfg, jnp.float32)
     one = jax.tree.map(lambda t: t + 0.1 * jax.random.normal(
         jax.random.PRNGKey(t.size), t.shape), one)     # biases not zero
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, PROMPT + GEN), 0,
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, prompt + GEN), 0,
                                 cfg.vocab_size)
 
     def serve(params, shard, place):
         last, caches = jax.jit(make_prefill_step(cfg, step_cfg, shard))(
-            params, {"tokens": tokens[:, :PROMPT]})
-        caches = place(decode_caches(cfg, caches, batch=B,
-                                     max_seq=PROMPT + GEN,
+            params, {"tokens": tokens[:, :prompt]})
+        caches = place(decode_caches(cfg, caches, batch=B, max_seq=MAX_SEQ,
                                      compute_dtype=jnp.float32))
         decode = jax.jit(make_decode_step(cfg, step_cfg, shard))
         out = [last]
-        for t in range(PROMPT, PROMPT + GEN - 1):
+        for t in range(prompt, prompt + GEN - 1):
             logits, caches = decode(params, {"tokens": tokens[:, t:t + 1]},
                                     caches)
             out.append(logits)
         text = decode.lower(params, {"tokens": tokens[:, :1]},
                             caches).compile().as_text()
-        return np.stack([np.asarray(o) for o in out], 1), text
+        return np.stack([np.asarray(o) for o in out], 1), caches, text
 
     kv = NamedSharding(mesh, plan.cache_spec("kv"))
     length = NamedSharding(mesh, plan.cache_spec("kv_len"))
-    want, _ = serve(one, None, lambda c: c)
-    got, text = serve(
+    want, want_caches, _ = serve(one, None, lambda c: c)
+    got, got_caches, text = serve(
         jax.device_put(one, plan.params_shardings(one)), shard,
         lambda c: jax.device_put(c, c._replace(k=kv, v=kv, length=length)))
     hd = cfg.resolved_head_dim
+    cache_gap = max(float(np.max(np.abs(np.asarray(a) - np.asarray(b))))
+                    for a, b in zip(jax.tree.leaves(got_caches),
+                                    jax.tree.leaves(want_caches)))
     return {"gap": float(np.max(np.abs(got - want)) / np.max(np.abs(want))),
-            "kv_splits": shard.kv_splits, "hlo": text,
-            "cache_shard": B * (PROMPT + GEN) // 4 * cfg.n_kv_heads * hd,
+            "cache_gap": cache_gap, "kv_splits": shard.kv_splits,
+            "length": np.asarray(got_caches.length).tolist(), "hlo": text,
+            "cache_shard": B * MAX_SEQ // 4 * cfg.n_kv_heads * hd,
             "least_weight": min(cfg.d_model * cfg.n_kv_heads * hd,
                                 cfg.d_model * cfg.d_ff)}
 
 
 @pytest.fixture(scope="module")
-def out():
+def runs():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.pathsep.join([os.path.join(REPO, "src"),
                                            os.path.join(REPO, "tests")]))
     code = ("import json; from test_glm4_sharded import "
-            "sharded_vs_one_device as f; print(json.dumps(f()))")
+            "sharded_vs_one_device as f; "
+            f"print(json.dumps([f(), f({CROSSING})]))")
     p = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
                        capture_output=True, text=True, timeout=300)
     assert p.returncode == 0, p.stderr[-3000:]
     return json.loads(p.stdout.strip().splitlines()[-1])
 
 
+@pytest.fixture(scope="module")
+def out(runs):
+    return runs[0]
+
+
 def test_sharded_decode_matches_one_device(out):
     assert out["kv_splits"] == 4
     assert out["gap"] < TOL, out["gap"]
+
+
+def test_sharded_decode_writes_across_parts(runs):
+    """Decode writes that cross from one device's part of the sequence
+    into the next land where one device puts them."""
+    crossing = runs[1]
+    assert crossing["gap"] < TOL, crossing["gap"]
+    assert crossing["cache_gap"] < TOL, crossing["cache_gap"]
+    assert {n for row in crossing["length"] for n in row} == \
+        {CROSSING + GEN - 1}
 
 
 def _elements(shape: str) -> int:

@@ -534,8 +534,8 @@ def test_arch_goodput_scenario():
 
 def test_decode_cache_sized_to_prompt_plus_gen():
     """Regression for the hardcoded ``max_seq = 64`` bug in
-    examples/serve_lm.py: the decode step appends via a one-hot(length)
-    scatter that *silently drops* writes past the padded cache length.
+    examples/serve_lm.py: the decode step writes at each row's length
+    and *silently drops* writes past the padded cache length.
     Sizing the cache to exactly prompt + generated must keep every write
     in bounds: the final cache length equals prompt+gen and the last
     position really was written (nonzero keys)."""

@@ -102,3 +102,27 @@ def test_ssd_scan_compiles(one_chip):
     _compile(ssd_intra_chunk, one_chip, (big + (64,), jnp.float32),
              (big + (64,), jnp.float32), (big, jnp.float32),
              (big, jnp.float32), (big + (64,), jnp.float32))
+
+
+def test_decode_step_writes_the_cache_in_place(one_chip):
+    """minicpm-2b's decode step at its published widths (batch 8, 1152
+    positions, the cache donated) needs no temporary of even one layer's
+    K cache: the one new position is written into the cache where it
+    lies, and the cache keeps the layout the device gave it."""
+    from repro.configs import get_config
+    from repro.models.transformer import init_model
+    from repro.train.steps import StepConfig, init_caches, make_decode_step
+
+    cfg = get_config("minicpm-2b")
+    batch, max_seq = 8, 1152
+    put = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda k: init_model(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0)))
+    caches = jax.tree.map(put, jax.eval_shape(
+        lambda: init_caches(cfg, batch, max_seq)))
+    tok = put(jax.ShapeDtypeStruct((batch, 1), jnp.int32))
+    step = make_decode_step(cfg, StepConfig(remat=False))
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        params, {"tokens": tok}, caches).compile()
+    layer_k = caches.k.size * caches.k.dtype.itemsize // cfg.n_layers
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k
