@@ -1,42 +1,14 @@
-"""Assemble EXPERIMENTS.md from reports/ artifacts (dry-run, roofline,
-benchmarks, perf iterations). Narrative sections live in
-benchmarks/experiments_narrative.md and are included verbatim.
+"""Assemble EXPERIMENTS.md from the benchmarks' reports/ artifacts.
+Narrative sections live in benchmarks/experiments_narrative.md and are
+included verbatim.
 
     PYTHONPATH=src python -m benchmarks.make_experiments_md
 """
 
 from __future__ import annotations
 
-import glob
 import json
 import os
-
-from repro.launch.roofline import format_table, load_reports, roofline_terms
-
-
-def dryrun_summary(report_dir="reports/dryrun") -> str:
-    recs = load_reports(report_dir)
-    n_ok = sum(r["status"] == "ok" for r in recs)
-    n_skip = sum(r["status"] == "skipped" for r in recs)
-    n_err = sum(r["status"] == "error" for r in recs)
-    lines = [f"Cells lowered+compiled: **{n_ok} ok**, {n_skip} skipped "
-             f"(assignment rule), {n_err} errors, of {len(recs)} total.",
-             "",
-             "| arch | shape | mesh | status | compile s | HBM GB/dev |",
-             "|---|---|---|---|---|---|"]
-    for r in sorted(recs, key=lambda r: (r["arch"], r["shape"],
-                                         r["multi_pod"])):
-        mesh = "2x16x16" if r["multi_pod"] else "16x16"
-        if r["status"] == "ok":
-            t = roofline_terms(r)
-            hbm = f"{t['hbm_gb_per_device']:.2f}"
-        else:
-            hbm = "-"
-        note = r["status"] if r["status"] != "error" else \
-            "error: " + r.get("error", "")[:60]
-        lines.append(f"| {r['arch']} | {r['shape']} | {mesh} | {note} | "
-                     f"{r.get('seconds', '-')} | {hbm} |")
-    return "\n".join(lines)
 
 
 def bench_section() -> str:
@@ -142,28 +114,6 @@ def bench_section() -> str:
     return "\n\n".join(out)
 
 
-def perf_section() -> str:
-    rows = []
-    for p in sorted(glob.glob("reports/perf/*.json")):
-        r = json.load(open(p))
-        b, a = r.get("before"), r.get("after")
-        if not a:
-            rows.append(f"- `{r['cell']}` / **{r['variant']}** — FAILED "
-                        f"({r['after_raw'].get('error', '')[:80]})")
-            continue
-        def fmt(t):
-            return (f"comp {t['t_compute_s']*1e3:.1f}ms, "
-                    f"mem {t['t_memory_s']*1e3:.1f}ms, "
-                    f"coll {t['t_collective_s']*1e3:.1f}ms, "
-                    f"HBM {t['hbm_gb_per_device']:.1f}GB, "
-                    f"frac {t['roofline_fraction']:.4f}")
-        before = fmt(b) if b and b.get("status") == "ok" else "n/a"
-        rows.append(
-            f"- `{r['cell']}` / **{r['variant']}** — {r['hypothesis']}\n"
-            f"  - before: {before}\n  - after:  {fmt(a)}")
-    return "\n".join(rows) if rows else "(populated by perf_hillclimb runs)"
-
-
 def main():
     narrative = ""
     np_path = "benchmarks/experiments_narrative.md"
@@ -173,27 +123,9 @@ def main():
 
 {narrative}
 
-## §Dry-run (deliverable e)
-
-{dryrun_summary()}
-
-## §Roofline (deliverable g) — single-pod (16, 16) = 256 chips
-
-Hardware constants: 197 TFLOP/s bf16, 819 GB/s HBM, 4x50 GB/s ICI per chip.
-
-{format_table('reports/dryrun', multi_pod=False)}
-
-### Multi-pod (2, 16, 16) = 512 chips — lowering/compile proof
-
-{format_table('reports/dryrun', multi_pod=True)}
-
 ## §Paper validation (deliverables b, d)
 
 {bench_section()}
-
-## §Perf (hillclimb iterations)
-
-{perf_section()}
 """
     with open("EXPERIMENTS.md", "w") as f:
         f.write(doc)

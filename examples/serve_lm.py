@@ -1,6 +1,6 @@
 """Serving example: prefill + batched autoregressive decode with KV caches
-(reduced glm4-9b config on CPU; the same step functions the dry-run lowers
-for the production mesh), then the CIM side of the same question: the model
+(reduced glm4-9b config on CPU; the same step functions the chip
+benchmark runs), then the CIM side of the same question: the model
 frontend (core/frontend.py) lowers this exact serving config to its
 weight-GEMM workload, MIREDO reports the optimized dataflow mapping, and
 the measured-execution backend (core/executor.py) actually *runs* the

@@ -2,9 +2,9 @@
 ``jax.Device.device_kind``.
 
 One table serves every consumer: the TPU bridge's block-shape MIP
-(`core/tpu_bridge.py`), the kernels' VMEM request, the dry-run roofline
-(`launch/roofline.py`) and the chip smoke run, which refuses a device whose
-kind is missing here instead of assuming another chip's peaks.
+(`core/tpu_bridge.py`), the kernels' VMEM request and the chip smoke run,
+which refuses a device whose kind is missing here instead of assuming
+another chip's peaks.
 """
 
 from __future__ import annotations
@@ -35,7 +35,7 @@ CHIPS = {
                "docs"),
 }
 
-#: The chip the bridge, the kernels and the dry-run plan for.
+#: The chip the bridge and the kernels plan for.
 TARGET_KIND = "TPU v5 lite"
 
 

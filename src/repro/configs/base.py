@@ -220,7 +220,7 @@ SHAPES = {
 
 
 def applicable_shapes(cfg: ModelConfig) -> dict[str, ShapeSpec | None]:
-    """Shape cells for an arch; None = skipped (with reason in dryrun log).
+    """Shape cells for an arch; None = skipped.
 
     ``long_500k`` requires sub-quadratic sequence mixing: run for SSM/hybrid
     archs only (assignment rule; see DESIGN.md §Arch-applicability).

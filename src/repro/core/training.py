@@ -60,8 +60,8 @@ from repro.configs.base import ModelConfig, ShapeSpec
 from repro.core import workload as wl
 from repro.core.arch import WEIGHT
 
-#: Optimizer-state byte widths (`train/optimizer.py`: OPT_STATE_DTYPE is
-#: fp32 for gradients and both Adam moments); the updated weight image is
+#: Optimizer-state byte widths (`train/optimizer.py` keeps fp32 gradients
+#: and both Adam moments for fp32 parameters); the updated weight image is
 #: written back requantized to the macros' INT8.
 GRAD_BYTES = 4
 MOMENT_BYTES = 4

@@ -23,18 +23,12 @@ from repro.tracing import region
 
 
 class KVCache(NamedTuple):
-    k: jax.Array          # (B, S, KV, hd) — bf16, or int8 when quantized
+    k: jax.Array          # (B, S, KV, hd); (L, B, ...) for a layer stack
     v: jax.Array          # (B, S, KV, hd)
     length: jax.Array     # (B,) int32 — valid prefix length
-    k_scale: jax.Array | None = None   # (B, S, KV, 1) f32 when int8
-    v_scale: jax.Array | None = None
-
-
-# Module-level implementation switches (same pattern as
-# transformer.SCAN_UNROLL / moe.MOE_DISPATCH — flipped per-variant by the
-# dry-run and the perf harness, defaults = baseline):
-ATTN_IMPL = "chunked"     # "naive" | "chunked" (flash-style online softmax)
-KV_QUANT = False          # int8 KV cache (capacity optimization)
+    # Always None; kept because the decode benchmark's restore names them.
+    k_scale: None = None
+    v_scale: None = None
 
 
 def init_attention(key, d_model: int, n_heads: int, n_kv_heads: int,
@@ -90,7 +84,7 @@ def dot_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Flash-style online-softmax attention expressed in XLA (scan over KV
     blocks, f32 running statistics, bf16 score/prob tensors): the (Lq, Lk)
     f32 score tensor is never materialized — the HBM-traffic reduction the
-    Pallas kernel realizes on TPU, available to the dry-run cost model.
+    Pallas kernel realizes on TPU.
     Fully-masked causal blocks are skipped via the score mask (XLA DCEs the
     constant branch under unrolled scans)."""
     bsz, lq, h, hd = q.shape
@@ -216,18 +210,6 @@ def write_position(buf: jax.Array, new: jax.Array, length: jax.Array,
     return buf
 
 
-def quantize_kv(x: jax.Array):
-    """Per-(position, head) symmetric int8 KV quantization."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
-    return q.astype(jnp.int8), scale.astype(jnp.float32)
-
-
-def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
-    return (q.astype(jnp.float32) * scale).astype(dtype)
-
-
 @region("attn")
 def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
               head_dim: int, rope_theta: float, causal: bool = True,
@@ -239,14 +221,14 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
     holds the sequence's K/V. Decode: x is (B, 1, D) and cache holds past
     K/V, either one layer's (``layer`` None) or the whole layer stack
     (leaves (L, B, ...), ``layer`` the index of this one). The decode
-    writes the new K and V (and, int8, their scales) at each row's
-    ``length`` (``write_position``; on each device's part where the
-    cache's sequence axis is split), in place where the caller donated
-    the cache, and advances that layer's ``length``; a write past the
-    cache's end is dropped while ``length`` still rises. It then attends
-    over the positions before ``length + 1``, and returns the cache it
-    was given with those writes. Named scopes (``repro.tracing``): ``attn`` around it all,
-    ``rope`` around the rotary, ``kv_update`` around the cache write, and
+    writes the new K and V at each row's ``length`` (``write_position``;
+    on each device's part where the cache's sequence axis is split), in
+    place where the caller donated the cache, and advances that layer's
+    ``length``; a write past the cache's end is dropped while ``length``
+    still rises. It then attends over the positions before
+    ``length + 1``, and returns the cache it was given with those writes.
+    Named scopes (``repro.tracing``): ``attn`` around it all, ``rope``
+    around the rotary, ``kv_update`` around the cache write, and
     ``grouped_decode_attention``'s."""
     b, l, _ = x.shape
     q = dense(params["wq"], x).reshape(b, l, n_heads, head_dim)
@@ -267,19 +249,11 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
         if use_flash and causal and l >= 512:
             from repro.kernels.flash_attention.ops import flash_attention
             out = flash_attention(q, kf, vf, causal=True)
-        elif ATTN_IMPL == "chunked" and l >= 2048:
+        elif l >= 2048:
             out = dot_attention_chunked(q, kf, vf, causal=causal)
         else:
             out = dot_attention(q, kf, vf, causal=causal)
-        if KV_QUANT:
-            qk, sk = quantize_kv(k)
-            qv, sv = quantize_kv(v)
-            new_cache = KVCache(k=qk, v=qv,
-                                length=jnp.full((b,), l, jnp.int32),
-                                k_scale=sk, v_scale=sv)
-        else:
-            new_cache = KVCache(k=k, v=v,
-                                length=jnp.full((b,), l, jnp.int32))
+        new_cache = KVCache(k=k, v=v, length=jnp.full((b,), l, jnp.int32))
     else:
         # single-token decode against the cache: write the new position,
         # then attend over the positions before length + 1
@@ -298,17 +272,8 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
             return put(buf, new[:, 0], length, *lead)
 
         with region("kv_update"):
-            if cache.k_scale is None:
-                new_cache = cache._replace(k=write(cache.k, k),
-                                           v=write(cache.v, v))
-            else:
-                qk, sk = quantize_kv(k)
-                qv, sv = quantize_kv(v)
-                new_cache = cache._replace(
-                    k=write(cache.k, qk), v=write(cache.v, qv),
-                    k_scale=write(cache.k_scale, sk),
-                    v_scale=write(cache.v_scale, sv))
-            new_cache = new_cache._replace(
+            new_cache = cache._replace(
+                k=write(cache.k, k), v=write(cache.v, v),
                 length=cache.length + 1 if layer is None
                 else cache.length.at[layer].add(1))
 
@@ -316,9 +281,6 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
             return t if layer is None else t[layer]
 
         kf, vf = this_layer(new_cache.k), this_layer(new_cache.v)
-        if cache.k_scale is not None:
-            kf = dequantize_kv(kf, this_layer(new_cache.k_scale), x.dtype)
-            vf = dequantize_kv(vf, this_layer(new_cache.v_scale), x.dtype)
         if rep == 1 and splits == 1:
             out = dot_attention(q, kf, vf, causal=False,
                                 kv_length=length + 1)
@@ -330,9 +292,11 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
     return dense(params["wo"], out), new_cache
 
 
-def init_kv_cache(batch: int, max_seq: int, n_kv_heads: int, head_dim: int,
-                  dtype=jnp.bfloat16) -> KVCache:
-    return KVCache(
-        k=jnp.zeros((batch, max_seq, n_kv_heads, head_dim), dtype),
-        v=jnp.zeros((batch, max_seq, n_kv_heads, head_dim), dtype),
-        length=jnp.zeros((batch,), jnp.int32))
+def init_kv_cache(n_layers: int, batch: int, max_seq: int, n_kv_heads: int,
+                  head_dim: int, dtype=jnp.bfloat16) -> KVCache:
+    """The empty decode cache of ``n_layers`` layers: K and V of shape
+    (n_layers, batch, max_seq, n_kv_heads, head_dim) in ``dtype``, and
+    lengths (n_layers, batch) of 0."""
+    shape = (n_layers, batch, max_seq, n_kv_heads, head_dim)
+    return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+                   length=jnp.zeros((n_layers, batch), jnp.int32))

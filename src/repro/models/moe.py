@@ -1,7 +1,8 @@
-"""Mixture-of-Experts layer: top-k routing with fixed expert capacity
-(dispatch/combine einsums — the standard TPU-friendly formulation that
-shards cleanly under EP), plus optional shared experts (Qwen-MoE) and a
-dense residual branch (Arctic)."""
+"""Mixture-of-Experts layer: top-k routing with fixed expert capacity,
+plus optional shared experts (Qwen-MoE) and a dense residual branch
+(Arctic). Tokens reach their experts' buffers by an indexed scatter and
+come back by a gather, in O(T·K + E·C·D) memory; a one-hot dispatch's
+(T, E, C) tensors would be ruinous at arctic's scale."""
 
 from __future__ import annotations
 
@@ -9,12 +10,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import Identity, init_dense, init_mlp, mlp
-
-# Dispatch implementation. "einsum" is the textbook dense dispatch/combine
-# (one-hot (T,E,C) tensors — O(T·E·C) memory: simple but catastrophic at
-# arctic scale); "scatter" is the production path (sorted scatter/gather,
-# O(T·K + E·C·D) memory). See EXPERIMENTS.md §Perf iteration 1.
-MOE_DISPATCH = "scatter"
 
 
 def init_moe(key, d_model: int, d_ff: int, n_experts: int,
@@ -73,39 +68,23 @@ def moe(params: dict, x: jax.Array, *, n_experts: int, top_k: int,
     keep = pos_in_expert < capacity                             # (T, K)
     gate_vals = gate_vals * keep
 
-    if MOE_DISPATCH == "scatter":
-        # production path: indexed scatter/gather, no (T,E,C) tensors
-        dest = expert_idx * capacity + jnp.minimum(pos_in_expert,
-                                                   capacity - 1)  # (T,K)
-        dest = jnp.where(keep, dest, n_experts * capacity)        # dropped
-        flat_dest = dest.reshape(-1)                              # (T*K,)
-        src = jnp.repeat(jnp.arange(n_tok), top_k)
-        buf = jnp.zeros((n_experts * capacity + 1, d), x.dtype)
-        expert_in = buf.at[flat_dest].add(tokens[src])[:-1]
-        expert_in = expert_in.reshape(n_experts, capacity, d)
-        expert_in = shard("moe_expert_in", expert_in)
-        expert_out = _expert_ffn(params["experts"], expert_in, gated)
-        expert_out = shard("moe_expert_out", expert_out)
-        flat_out = expert_out.reshape(n_experts * capacity, d)
-        flat_out = jnp.concatenate(
-            [flat_out, jnp.zeros((1, d), x.dtype)], axis=0)
-        picked = flat_out[flat_dest].reshape(n_tok, top_k, d)
-        out = jnp.sum(picked * gate_vals[..., None].astype(x.dtype),
-                      axis=1)
-    else:
-        # dense one-hot dispatch (textbook formulation; O(T*E*C) memory —
-        # kept as the measurable baseline, see EXPERIMENTS.md §Perf)
-        pos_oh = jax.nn.one_hot(jnp.where(keep, pos_in_expert, capacity),
-                                capacity, dtype=x.dtype)        # (T,K,C)
-        disp = jnp.einsum("tke,tkc->tec", onehot.astype(x.dtype), pos_oh)
-        expert_in = jnp.einsum("td,tec->ecd", tokens, disp)
-        expert_in = shard("moe_expert_in", expert_in)
-        expert_out = _expert_ffn(params["experts"], expert_in, gated)
-        expert_out = shard("moe_expert_out", expert_out)
-        combine = jnp.einsum("tec,tk,tke->tec", disp,
-                             gate_vals.astype(x.dtype),
-                             onehot.astype(x.dtype))
-        out = jnp.einsum("ecd,tec->td", expert_out, combine)
+    # indexed scatter/gather: no (T, E, C) tensors
+    dest = expert_idx * capacity + jnp.minimum(pos_in_expert,
+                                               capacity - 1)    # (T,K)
+    dest = jnp.where(keep, dest, n_experts * capacity)          # dropped
+    flat_dest = dest.reshape(-1)                                # (T*K,)
+    src = jnp.repeat(jnp.arange(n_tok), top_k)
+    buf = jnp.zeros((n_experts * capacity + 1, d), x.dtype)
+    expert_in = buf.at[flat_dest].add(tokens[src])[:-1]
+    expert_in = expert_in.reshape(n_experts, capacity, d)
+    expert_in = shard("moe_expert_in", expert_in)
+    expert_out = _expert_ffn(params["experts"], expert_in, gated)
+    expert_out = shard("moe_expert_out", expert_out)
+    flat_out = expert_out.reshape(n_experts * capacity, d)
+    flat_out = jnp.concatenate(
+        [flat_out, jnp.zeros((1, d), x.dtype)], axis=0)
+    picked = flat_out[flat_dest].reshape(n_tok, top_k, d)
+    out = jnp.sum(picked * gate_vals[..., None].astype(x.dtype), axis=1)
 
     if "shared" in params:
         n_sh = params["shared"]["up"]["w"].shape[0]

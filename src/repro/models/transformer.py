@@ -9,7 +9,7 @@ One ``init_model`` / ``forward`` pair covers:
   encdec       : bidirectional encoder + causal decoder w/ cross-attention
                  (Seamless backbone; audio frontend stubbed)
 
-Layers are stacked and driven by ``jax.lax.scan`` (small HLO, fast 512-way
+Layers are stacked and driven by ``jax.lax.scan`` (small HLO, fast
 compile); training wraps the block in ``jax.checkpoint``. Modes:
   "train"   tokens -> logits                  (full seq, causal)
   "prefill" tokens -> logits + caches         (the scan's ys)
@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.attention import (KVCache, attention, init_attention,
+from repro.models.attention import (attention, init_attention,
                                     init_kv_cache)
 from repro.models.layers import (Identity, embed, init_embedding, init_mlp,
                                  init_rmsnorm, mlp, rms_norm, unembed)
@@ -37,17 +37,6 @@ from repro.models.moe import init_moe, moe
 from repro.models.ssm import (SSMState, init_mamba2, init_ssm_state,
                               mamba2_block)
 from repro.tracing import region
-
-
-# Scan-over-layers unrolling. XLA's cost model counts a while-loop body
-# once regardless of trip count; the dry-run sets this to True for its two
-# small exact-cost compiles (launch/dryrun.py) and leaves scans rolled for
-# the real (memory-accurate, fast-compile) artifact.
-SCAN_UNROLL: int | bool = 1
-
-
-def _scan(f, init, xs):
-    return jax.lax.scan(f, init, xs, unroll=SCAN_UNROLL)
 
 
 def _stack_init(init_fn, key, n: int):
@@ -217,19 +206,10 @@ def _scan_attn_layers(params_stack, x, cfg, caches, *, causal, shard,
 
     fn = jax.checkpoint(body) if remat else body
     n = jax.tree.leaves(params_stack)[0].shape[0]
-    (x, aux, stack), ys = _scan(
+    (x, aux, stack), ys = jax.lax.scan(
         fn, (x, jnp.zeros((), jnp.float32), caches),
         (params_stack, jnp.arange(n)))
     return x, aux, ys if caches is None else stack
-
-
-def _dummy_caches(n_layers, batch, max_seq, cfg, dtype):
-    return KVCache(
-        k=jnp.zeros((n_layers, batch, max_seq, cfg.n_kv_heads,
-                     cfg.resolved_head_dim), dtype),
-        v=jnp.zeros((n_layers, batch, max_seq, cfg.n_kv_heads,
-                     cfg.resolved_head_dim), dtype),
-        length=jnp.zeros((n_layers, batch), jnp.int32))
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
@@ -308,7 +288,7 @@ def _ssm_stack(stack, x, cfg, states, shard, remat, is_decode):
         return x + out, new_st
 
     fn = jax.checkpoint(body) if remat else body
-    x, new_states = _scan(fn, x, (stack, states))
+    x, new_states = jax.lax.scan(fn, x, (stack, states))
     return x, new_states, jnp.zeros((), jnp.float32)
 
 
@@ -348,7 +328,7 @@ def _hybrid_stack(params, x, cfg, caches, shard, remat, is_decode,
     def group_body(carry, layer_in):
         x = carry
         blocks_g, states_g, kv_g = layer_in
-        x, new_states_g = _scan(mamba_fn, x, (blocks_g, states_g))
+        x, new_states_g = jax.lax.scan(mamba_fn, x, (blocks_g, states_g))
         # parameter-shared attention block
         x, new_kv, aux, _ = _attn_block_apply(
             params["shared_attn"], x, cfg,
@@ -357,17 +337,17 @@ def _hybrid_stack(params, x, cfg, caches, shard, remat, is_decode,
         return x, (new_states_g, new_kv)
 
     if kv is None:
-        kv_stack = _dummy_caches(n_groups, b, max_seq, cfg, compute_dtype)
+        kv_stack = init_kv_cache(n_groups, b, max_seq, cfg.n_kv_heads,
+                                 cfg.resolved_head_dim, compute_dtype)
     else:
         kv_stack = kv
-    gfn = group_body
-    x, (new_grouped_states, new_kv_stack) = _scan(
-        gfn, x, (grouped_params, grouped_states, kv_stack))
+    x, (new_grouped_states, new_kv_stack) = jax.lax.scan(
+        group_body, x, (grouped_params, grouped_states, kv_stack))
     new_main = jax.tree.map(
         lambda t: t.reshape((grouped,) + t.shape[2:]), new_grouped_states)
     if tail_n:
         tail_states = jax.tree.map(lambda t: t[grouped:], ssm_states)
-        x, new_tail = _scan(mamba_fn, x,
+        x, new_tail = jax.lax.scan(mamba_fn, x,
                                    (params["tail"], tail_states))
         new_states = jax.tree.map(
             lambda a, c: jnp.concatenate([a, c], axis=0), new_main, new_tail)

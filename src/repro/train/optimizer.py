@@ -21,12 +21,6 @@ class AdamWState(NamedTuple):
     v: dict
 
 
-# Optimizer-moment storage dtype (module switch, perf-harness pattern):
-# fp32 is the baseline; bf16 halves optimizer HBM at ~equal convergence
-# (stochastic-rounding-free bf16 moments are standard at this scale).
-OPT_STATE_DTYPE = jnp.float32
-
-
 @dataclasses.dataclass(frozen=True)
 class OptimizerConfig:
     lr: float = 3e-4
@@ -62,9 +56,9 @@ def schedule_lr(cfg: OptimizerConfig, step: jax.Array) -> jax.Array:
 
 
 def init_adamw(params) -> AdamWState:
-    zeros = lambda: jax.tree.map(
-        lambda p: jnp.zeros(p.shape, OPT_STATE_DTYPE
-                            if p.dtype == jnp.float32 else p.dtype), params)
+    """Zero moments in each parameter's shape and dtype, at step 0."""
+    zeros = lambda: jax.tree.map(lambda p: jnp.zeros(p.shape, p.dtype),
+                                 params)
     return AdamWState(step=jnp.zeros((), jnp.int32), m=zeros(), v=zeros())
 
 

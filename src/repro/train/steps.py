@@ -5,8 +5,7 @@ optional microbatch gradient accumulation (lax.scan) and cross-pod int8
 gradient compression with error feedback. ``make_prefill_step`` /
 ``make_decode_step``: serving counterparts carrying KV caches / SSM states.
 
-All steps are pure functions of (state, batch) so they pjit cleanly; the
-dry-run lowers them with ShapeDtypeStructs.
+All steps are pure functions of (state, batch) so they pjit cleanly.
 """
 
 from __future__ import annotations
@@ -20,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.models import transformer
-from repro.models.attention import KVCache
+from repro.models.attention import init_kv_cache
 from repro.models.ssm import SSMState, init_ssm_state, ssd_dims
 from repro.runtime.compression import (compress_grads_with_feedback,
                                        init_residuals)
@@ -166,30 +165,14 @@ def make_decode_step(cfg: ModelConfig, step_cfg: StepConfig, shard=None):
     return decode
 
 
-def _kv_cache_stack(n: int, batch: int, max_seq: int, kv: int, hd: int,
-                    compute_dtype):
-    import repro.models.attention as attn_mod
-    if attn_mod.KV_QUANT:
-        return KVCache(
-            k=jnp.zeros((n, batch, max_seq, kv, hd), jnp.int8),
-            v=jnp.zeros((n, batch, max_seq, kv, hd), jnp.int8),
-            length=jnp.zeros((n, batch), jnp.int32),
-            k_scale=jnp.zeros((n, batch, max_seq, kv, 1), jnp.float32),
-            v_scale=jnp.zeros((n, batch, max_seq, kv, 1), jnp.float32))
-    return KVCache(
-        k=jnp.zeros((n, batch, max_seq, kv, hd), compute_dtype),
-        v=jnp.zeros((n, batch, max_seq, kv, hd), compute_dtype),
-        length=jnp.zeros((n, batch), jnp.int32))
-
-
 def init_caches(cfg: ModelConfig, batch: int, max_seq: int,
                 compute_dtype=jnp.bfloat16):
     """Decode-mode cache pytree (zeros), family-dependent."""
     fam = cfg.family
     hd = cfg.resolved_head_dim
     if fam in ("dense", "moe", "vlm"):
-        return _kv_cache_stack(cfg.n_layers, batch, max_seq,
-                               cfg.n_kv_heads, hd, compute_dtype)
+        return init_kv_cache(cfg.n_layers, batch, max_seq, cfg.n_kv_heads,
+                             hd, compute_dtype)
     if fam == "ssm":
         st = init_ssm_state(batch, cfg, cfg.d_model)
         return jax.tree.map(
@@ -198,21 +181,12 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int,
         st = init_ssm_state(batch, cfg, cfg.d_model)
         states = jax.tree.map(
             lambda t: jnp.zeros((cfg.n_layers,) + t.shape, t.dtype), st)
-        n_groups = cfg.n_layers // cfg.attn_every
-        kv = KVCache(
-            k=jnp.zeros((n_groups, batch, max_seq, cfg.n_kv_heads, hd),
-                        compute_dtype),
-            v=jnp.zeros((n_groups, batch, max_seq, cfg.n_kv_heads, hd),
-                        compute_dtype),
-            length=jnp.zeros((n_groups, batch), jnp.int32))
+        kv = init_kv_cache(cfg.n_layers // cfg.attn_every, batch, max_seq,
+                           cfg.n_kv_heads, hd, compute_dtype)
         return (states, kv)
     if fam == "encdec":
-        kv = KVCache(
-            k=jnp.zeros((cfg.n_layers, batch, max_seq, cfg.n_kv_heads, hd),
-                        compute_dtype),
-            v=jnp.zeros((cfg.n_layers, batch, max_seq, cfg.n_kv_heads, hd),
-                        compute_dtype),
-            length=jnp.zeros((cfg.n_layers, batch), jnp.int32))
+        kv = init_kv_cache(cfg.n_layers, batch, max_seq, cfg.n_kv_heads, hd,
+                           compute_dtype)
         mem = cfg.frontend_seq or 1024
         cross = (jnp.zeros((cfg.n_layers, batch, mem, cfg.n_kv_heads, hd),
                            compute_dtype),

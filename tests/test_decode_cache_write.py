@@ -1,13 +1,13 @@
 """The decode step's KV-cache write, against the one-hot update it replaced.
 
-The decode writes each row's new K and V (and, with an int8 cache, their
-scales) at that row's ``length`` only, into the layer stack the layer scan
-carries. The reference below is the update the program used before: a
-one-hot(length) multiply-add over every position of the layer's cache,
-which drops a write past the cache's end. Swapped in for the program's
-write, it gives the reference decode; both run from the same caches, with
-rows at different lengths and one row already full, and must give the
-same logits and the same caches bit for bit.
+The decode writes each row's new K and V at that row's ``length`` only,
+into the layer stack the layer scan carries. The reference below is the
+update the program used before: a one-hot(length) multiply-add over
+every position of the layer's cache, which drops a write past the
+cache's end. Swapped in for the program's write, it gives the reference
+decode; both run from the same caches, with rows at different lengths
+and one row already full, and must give the same logits and the same
+caches bit for bit. ``init_caches`` builds every family's decode cache.
 """
 
 from __future__ import annotations
@@ -21,6 +21,7 @@ import pytest
 
 import repro.models.attention as attn_mod
 from repro.configs import get_config
+from repro.models.attention import KVCache
 from repro.train.steps import (StepConfig, decode_caches, init_caches,
                                init_train_state, make_decode_step,
                                make_prefill_step)
@@ -54,11 +55,10 @@ def _caches(cfg, params, prompt, lengths, max_seq):
                            STEP.compute_dtype)
     lengths = jnp.asarray(lengths, jnp.int32)
     keep = jnp.arange(max_seq)[None, :] < lengths[:, None]      # (B, S)
-    zero = lambda t: None if t is None else jnp.where(
-        keep[None, :, :, None, None], t, jnp.zeros((), t.dtype))
+    zero = lambda t: jnp.where(keep[None, :, :, None, None], t,
+                               jnp.zeros((), t.dtype))
     return caches._replace(
         k=zero(caches.k), v=zero(caches.v),
-        k_scale=zero(caches.k_scale), v_scale=zero(caches.v_scale),
         length=jnp.broadcast_to(lengths, caches.length.shape))
 
 
@@ -72,21 +72,17 @@ def _decode(cfg, params, caches, tokens):
     return np.stack(out, 1), caches
 
 
-@pytest.fixture(params=[False, True], ids=["f32", "int8"])
-def kv_quant(request, monkeypatch):
-    monkeypatch.setattr(attn_mod, "KV_QUANT", request.param)
-    return request.param
-
-
 # max_seq 24: 8-position windows; 256: two 128-position windows, with a
-# row written across the boundary between them
+# row written across the boundary between them; 1152: the minicpm decode
+# cell's cache, nine 128-position windows, rows in the last and the sixth
+# and one row full
 @pytest.mark.parametrize("max_seq,lengths", [
     (24, [5, 12, 7, 24]),
     (256, [126, 9, 128, 256]),
+    (1152, [1024, 1151, 1152, 640]),
 ])
 @pytest.mark.parametrize("name", sorted(CONFIGS))
-def test_decode_write_matches_one_hot(name, max_seq, lengths, kv_quant,
-                                      monkeypatch):
+def test_decode_write_matches_one_hot(name, max_seq, lengths, monkeypatch):
     cfg = CONFIGS[name]
     params = init_train_state(jax.random.PRNGKey(0), cfg, STEP).params
     rng = np.random.default_rng(1)
@@ -94,7 +90,6 @@ def test_decode_write_matches_one_hot(name, max_seq, lengths, kv_quant,
                          jnp.int32)
     tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 3)), jnp.int32)
     start = _caches(cfg, params, prompt, lengths, max_seq)
-    assert (start.k.dtype == jnp.int8) == kv_quant
 
     got, got_caches = _decode(cfg, params, start, tokens)
     monkeypatch.setattr(attn_mod, "write_position", one_hot_write)
@@ -150,3 +145,33 @@ def test_layer_scan_carries_the_cache(name):
     assert carry.count(shape) == 2                  # K and V
     assert shape not in xs and shape not in ys
     assert ys == []
+
+
+# one config per family that carries a KV cache
+CACHE_CONFIGS = ["minicpm-2b", "qwen2-moe-a2.7b", "zamba2-1.2b",
+                 "seamless-m4t-large-v2"]
+
+
+@pytest.mark.parametrize("name", CACHE_CONFIGS)
+def test_init_caches_shapes(name):
+    """Every family's decode caches hold one empty ``KVCache`` stacked
+    over its attention layers (the hybrid's over its groups of layers):
+    K and V of (layers, batch, max_seq, KV heads, head dim) in the
+    requested dtype, lengths of 0, and no scales."""
+    cfg = get_config(name).reduced()
+    batch, max_seq = 3, 40
+    caches = init_caches(cfg, batch, max_seq, jnp.bfloat16)
+    kvs = [c for c in jax.tree.leaves(
+        caches, is_leaf=lambda c: isinstance(c, KVCache))
+        if isinstance(c, KVCache)]
+    assert len(kvs) == 1
+    kv, = kvs
+    layers = cfg.n_layers // cfg.attn_every if cfg.family == "hybrid" \
+        else cfg.n_layers
+    shape = (layers, batch, max_seq, cfg.n_kv_heads, cfg.resolved_head_dim)
+    assert kv.k.shape == kv.v.shape == shape
+    assert kv.k.dtype == kv.v.dtype == jnp.bfloat16
+    assert kv.length.shape == (layers, batch)
+    assert kv.length.dtype == jnp.int32
+    assert not np.any(np.asarray(kv.length))
+    assert kv.k_scale is None and kv.v_scale is None

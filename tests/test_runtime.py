@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.runtime.compression import (compress_grads_with_feedback,
                                        init_residuals)
@@ -36,6 +37,24 @@ def test_adamw_optimizes_quadratic():
         grads = {"w": 2 * params["w"]}
         params, state, m = adamw_update(cfg, params, grads, state)
     assert float(jnp.max(jnp.abs(params["w"]))) < 0.1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_adamw_moments_keep_param_dtype(dtype):
+    """Moments are zeros of each parameter's shape and dtype, and an update
+    keeps the parameters' and the moments' dtypes."""
+    params = {"w": jnp.ones((3, 4), dtype), "b": jnp.ones((4,), dtype)}
+    state = init_adamw(params)
+    for tree in (state.m, state.v):
+        for p, t in zip(jax.tree.leaves(params), jax.tree.leaves(tree)):
+            assert t.shape == p.shape and t.dtype == dtype
+            assert not np.any(np.asarray(t, np.float32))
+    cfg = OptimizerConfig(warmup_steps=0, total_steps=10)
+    grads = jax.tree.map(jnp.ones_like, params)
+    new, state, _ = adamw_update(cfg, params, grads, state)
+    for t in jax.tree.leaves((new, state.m, state.v)):
+        assert t.dtype == dtype
+    assert int(state.step) == 1
 
 
 def test_grad_clip_reported():
