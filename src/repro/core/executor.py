@@ -17,7 +17,9 @@ allows" demands:
        * weight GEMMs (projections, FFN/MoE mats, SSD state GEMMs, the LM
          head) -> `kernels/matmul_int8`, block shapes derived from the
          layer's *optimized mapping* by the TPU bridge
-         (`tpu_bridge.select_blocks_from_mapping`);
+         (`tpu_bridge.select_blocks_from_mapping`): the mapping's M and N
+         tiles and the whole reduction, as large as the double-buffered
+         VMEM budget allows;
        * one score/AV stage per attention block -> `kernels/
          flash_attention` (`tpu_bridge.select_flash_blocks`); decode runs
          the step against a synthetic KV cache, prefill the full causal
@@ -77,12 +79,6 @@ DECODE_KV_CAP = 512
 #: f32 reductions blockwise.
 NUMERICS_TOL = {"matmul_int8": 1e-4, "flash_attention": 2e-3,
                 "ssd_scan": 2e-3}
-
-#: Block-size cap for executed matmuls: per-grid-step wall-clock is the
-#: measurement granularity, so each op should span several steps — one
-#: mapping-sized mega-block would collapse every GEMM into a single opaque
-#: step and flatten the measured ranking the backend exists to test.
-EXEC_BLOCK_CAP = 128
 
 
 # ---------------------------------------------------------------------------
@@ -187,8 +183,7 @@ def _gemm_mkn(layer: wl.Layer) -> tuple[int, int, int]:
 def _matmul_op(idx: int, lr, arch: CimArch) -> ExecOp:
     m, k, n = _gemm_mkn(lr.layer)
     mapping = mapping_from_json(lr.record["mapping"])
-    c = select_blocks_from_mapping(mapping, lr.layer, arch,
-                                   cap=EXEC_BLOCK_CAP)
+    c = select_blocks_from_mapping(mapping, lr.layer, arch)
     return ExecOp(
         name=lr.layer.name, kernel="matmul_int8",
         spec={"m": m, "k": k, "n": n, "bm": c.bm, "bk": c.bk, "bn": c.bn},

@@ -143,17 +143,16 @@ def select_matmul_blocks(m: int, k: int, n: int, *,
                        sol.status.name)
 
 
-def _snap(hint: int, dim: int, *, align: int, cap: int = 2048) -> int:
+def _snap(hint: int, dim: int, *, align: int) -> int:
     """Round a mapping tile extent up to MXU alignment and clamp it into
-    [align, min(cap, dim padded to alignment)]."""
-    padded = max(align, min(_round_up(dim, align), cap - cap % align))
+    [align, min(2048, dim padded to alignment)]."""
+    padded = max(align, min(_round_up(dim, align), 2048))
     return max(align, min(_round_up(hint, align), padded))
 
 
 def select_blocks_from_mapping(mapping, layer, arch, *,
                                bytes_in: int = 1, bytes_acc: int = 4,
-                               vmem_bytes: int = VMEM_BYTES,
-                               cap: int = 2048) -> BlockChoice:
+                               vmem_bytes: int = VMEM_BYTES) -> BlockChoice:
     """Translate a solved MIREDO mapping into Pallas matmul block shapes.
 
     The measured-execution backend (`core/executor.py`) runs each optimized
@@ -162,16 +161,21 @@ def select_blocks_from_mapping(mapping, layer, arch, *,
     tile extent — spatial unrolls plus every temporal factor that *all*
     operands indexing the dim hold above DRAM — is the working set MIREDO
     decided to keep resident, i.e. the CIM analogue of the VMEM-resident
-    Pallas block. Each extent is snapped to MXU alignment (lane 128 /
-    sublane 8) and clamped to the padded dim; the working set is then
-    halved-down until the double-buffered eq. 9 capacity holds. Callers
-    zero-pad when a block does not divide the dim (kernels/matmul_int8/
-    ops.py), exactly as for `select_matmul_blocks` picks.
+    Pallas block. The M and N extents are snapped to MXU alignment (lane
+    128 / sublane 8) and clamped to the padded dim. Callers zero-pad when a
+    block does not divide the dim (kernels/matmul_int8/ops.py), exactly as
+    for `select_matmul_blocks` picks.
 
-    ``cap`` bounds every block dim; the measured-execution backend lowers
-    it so each op spans several grid steps (per-step wall-clock is the
-    measurement granularity — one giant block would time a single opaque
-    step).
+    The reduction block departs from the mapping: it is the whole K,
+    padded to the lane, whenever the double-buffered eq. 9 working set
+    still fits. The mapping's C tile is the macro's wordline count
+    (`CimArch.macro_rows`), a limit of the CIM array, not of the TPU,
+    whose kernel keeps the partial sums in a VMEM int32 accumulator: a
+    further K step saves no HBM byte (x and w are read the same either
+    way) and costs a grid step and a pass over that accumulator. Where the
+    whole K does not fit, the C tile is snapped like the others and the
+    working set is halved-down until eq. 9 holds. The int32 sum is exact,
+    so the result does not depend on the blocks.
     """
     from repro.core import workload as wl
 
@@ -186,10 +190,12 @@ def select_blocks_from_mapping(mapping, layer, arch, *,
                 mapping.level_of[lam][i] >= 1
                 for lam in mapping.level_of if wl.is_relevant(d, lam)):
             hints[d] *= f
-    bm = _snap(hints["N"], m, align=SUBLANE, cap=cap)
-    bk = _snap(hints["C"], k, align=LANE, cap=max(cap, LANE))
-    bn = _snap(hints["K"], n, align=LANE, cap=max(cap, LANE))
+    bm = _snap(hints["N"], m, align=SUBLANE)
+    bn = _snap(hints["K"], n, align=LANE)
+    bk = _round_up(k, LANE)
     ws = lambda: bm * bk * bytes_in + bk * bn * bytes_in + bm * bn * bytes_acc
+    if 2 * ws() > vmem_bytes:         # whole K does not fit: map C too
+        bk = _snap(hints["C"], k, align=LANE)
     while 2 * ws() > vmem_bytes:      # pipelined (double-buffered) eq. 9
         if bm >= max(bk, bn) and bm > SUBLANE:
             bm = max(SUBLANE, bm // 2 - bm // 2 % SUBLANE)

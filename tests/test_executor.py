@@ -10,10 +10,10 @@ from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.core import workload as wl
 from repro.core.arch import default_arch
-from repro.core.executor import (EXEC_BLOCK_CAP, ExecOp, execute_plan,
-                                 lower_plan, spearman)
+from repro.core.executor import ExecOp, execute_plan, lower_plan, spearman
 from repro.core.frontend import extract_workload
 from repro.core.network import optimize_network
+from repro.core.tpu_bridge import VMEM_BYTES
 
 ARCH = default_arch()
 PREFILL = ShapeSpec("t_prefill", seq_len=64, global_batch=1, kind="prefill")
@@ -99,15 +99,21 @@ def test_lower_plan_dense_prefill(dense_prefill):
               for i in op.layer_indices]
     assert sorted(mm_idx) == list(range(len(net.layers)))
     # every matmul op carries its record's cycles and mapping-derived,
-    # MXU-aligned blocks under the execution cap
+    # MXU-aligned blocks within the double-buffered VMEM budget, reducing
+    # over the whole (lane-padded) K in one step wherever that fits
     for op in plan.ops:
         if op.kernel != "matmul_int8":
             continue
         lr = net.layers[op.layer_indices[0]]
         assert op.predicted_cycles == lr.record["cycles"]
         s = op.spec
-        assert s["bm"] % 8 == 0 and s["bk"] % 128 == 0 and s["bn"] % 128 == 0
-        assert max(s["bm"], s["bk"], s["bn"]) <= max(EXEC_BLOCK_CAP, 128)
+        bm, bk, bn = s["bm"], s["bk"], s["bn"]
+        assert bm % 8 == 0 and bk % 128 == 0 and bn % 128 == 0
+        vmem = lambda bk: bm * bk + bk * bn + 4 * bm * bn
+        assert 2 * vmem(bk) <= VMEM_BYTES
+        kp = -(-s["k"] // 128) * 128
+        if 2 * vmem(kp) <= VMEM_BYTES:
+            assert bk == kp, (op.name, s)
     # prefill attention: causal square over the block's token dim
     fo = next(op for op in plan.ops if op.kernel == "flash_attention")
     assert fo.spec["causal"] and fo.spec["lq"] == fo.spec["lk"] == 64

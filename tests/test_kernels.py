@@ -94,8 +94,9 @@ def test_matmul_bridge_candidate_blocks_padded(m, k, n):
 
 def test_matmul_mapping_derived_blocks():
     """Blocks derived from an optimized CIM mapping
-    (`tpu_bridge.select_blocks_from_mapping`) are MXU-legal, capped, and
-    numerically exact vs the int8 oracle on identical quantized operands."""
+    (`tpu_bridge.select_blocks_from_mapping`) are MXU-legal, reduce over
+    the whole K, and are numerically exact vs the int8 oracle on identical
+    quantized operands."""
     from repro.core.arch import default_arch
     from repro.core.baselines import greedy_mapping
     from repro.core.tpu_bridge import select_blocks_from_mapping
@@ -104,9 +105,9 @@ def test_matmul_mapping_derived_blocks():
     arch = default_arch()
     layer = gemm("t.g", 96, 360, 200)       # (96 x 200) @ (200 x 360)
     mp = greedy_mapping(layer, arch)
-    c = select_blocks_from_mapping(mp, layer, arch, cap=128)
+    c = select_blocks_from_mapping(mp, layer, arch)
     assert c.bm % 8 == 0 and c.bk % 128 == 0 and c.bn % 128 == 0
-    assert max(c.bm, c.bk, c.bn) <= 256    # cap + alignment floor
+    assert c.bk == 256                      # K = 200 padded to the lane
     assert 2 * c.vmem_bytes <= 64 * 1024 * 1024
     rng = np.random.default_rng(9)
     x = jnp.asarray(rng.standard_normal((96, 200)), jnp.float32)
@@ -118,10 +119,53 @@ def test_matmul_mapping_derived_blocks():
                                rtol=1e-4, atol=1e-4)
 
 
+# (M, K, N, VMEM budget in bytes, whole K expected): minicpm-2b's four plan
+# GEMMs at prefill 1 x 2048 (wq/wk/wv/wo, ffn_up, ffn_down, the LM head),
+# a K whose whole reduction overflows the budget (the mapping's C tile
+# instead), and a budget so small that the mapping's tiles are halved too
+BRIDGE_CASES = {
+    "wq": (2048, 2304, 2304, None, True),
+    "ffn_up": (2048, 2304, 11520, None, True),
+    "ffn_down": (2048, 5760, 2304, None, True),
+    "lm_head": (1, 2304, 122880, None, True),
+    "k_over_vmem": (2048, 1 << 17, 2304, None, False),
+    "halved": (2048, 2304, 2304, 1 << 19, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BRIDGE_CASES))
+def test_mapping_blocks_reduce_whole_k(case):
+    """The bridge gives the whole (lane-padded) K as one reduction step
+    wherever the double-buffered eq. 9 working set fits, and otherwise
+    the mapping's C tile, halving the blocks until eq. 9 holds."""
+    from repro.core.arch import default_arch
+    from repro.core.baselines import greedy_mapping
+    from repro.core.tpu_bridge import VMEM_BYTES, select_blocks_from_mapping
+    from repro.core.workload import gemm
+    m, k, n, vmem, whole = BRIDGE_CASES[case]
+    vmem = vmem or VMEM_BYTES
+    arch = default_arch()
+    layer = gemm(f"t.{case}", m, n, k)
+    mp = greedy_mapping(layer, arch)
+    c = select_blocks_from_mapping(mp, layer, arch, vmem_bytes=vmem)
+    assert c.bm % 8 == 0 and c.bk % 128 == 0 and c.bn % 128 == 0
+    assert c.vmem_bytes == c.bm * c.bk + c.bk * c.bn + 4 * c.bm * c.bn
+    assert 2 * c.vmem_bytes <= vmem
+    k_steps = -(-k // c.bk)
+    assert (k_steps == 1) == whole, (c.bm, c.bk, c.bn)
+    if not whole:
+        assert c.bk <= arch.macro_rows      # the mapping's wordline tile
+    if vmem < VMEM_BYTES:                   # halved below the mapping's
+        full = select_blocks_from_mapping(mp, layer, arch)
+        assert c.bm * c.bn < full.bm * full.bn
+
+
 # (M, K, N, blocks): the LM head's shape class, one row against a vocabulary
-# that no bn divides (M, K and N padded), and one that needs no padding
+# that no bn divides (M, K and N padded), one that needs no padding, and
+# the bridge's whole-K reduction (K = 200 padded to one 256 block)
 PROGRAM_CASES = {"lm_head_padded": (1, 64, 200, (8, 32, 128)),
-                 "aligned": (32, 64, 48, (16, 32, 16))}
+                 "aligned": (32, 64, 48, (16, 32, 16)),
+                 "whole_k": (24, 200, 136, (8, 256, 128))}
 
 
 def _case_operands(m, k, n, seed):
@@ -150,6 +194,20 @@ def test_quantized_matmul_is_the_eager_composition(case):
                         out_dtype=jnp.float32, interpret=True)[:m, :n]
     assert out.shape == (m, n)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(eager))
+
+
+@pytest.mark.parametrize("case", sorted(PROGRAM_CASES))
+def test_quantized_matmul_matches_ref(case):
+    """The kernel and the plain int8 oracle, on the same quantized
+    operands, agree bit for bit whatever the blocks: the int32 sum is
+    exact and both apply the scales in one order."""
+    from repro.kernels.matmul_int8.ops import quantized_matmul_and_ref
+    m, k, n, blocks = PROGRAM_CASES[case]
+    x, w = _case_operands(m, k, n, 12)
+    out, ref = quantized_matmul_and_ref(x, w, block_shapes=blocks,
+                                        interpret=True)
+    assert out.shape == ref.shape == (m, n)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 @pytest.mark.parametrize("case", sorted(PROGRAM_CASES))

@@ -53,13 +53,15 @@ def _compile(fn, sharding, *shapes):
 
 
 # (M, K, N, (bm, bk, bn)): zamba2-1.2b's in_proj (2048 x 8384) at prefill
-# (2048 tokens) and batch-16 decode, with executor-capped blocks; N = 8384
-# is not a multiple of 128, so the op pads it
+# (2048 tokens) and batch-16 decode, with 128-wide blocks; N = 8384 is not
+# a multiple of 128, so the op pads it
 MATMUL_CASES = {
     "in_proj_prefill": (2048, 2048, 8384, (128, 128, 128)),
     "in_proj_decode": (16, 2048, 8384, (16, 128, 128)),
     # rank-1 (bm,) scale blocks were illegal here: bm < 128 and bm != M
     "scale_block_bm64": (2048, 2048, 2048, (64, 128, 128)),
+    # the executor's whole-K reduction at minicpm-2b's ffn_down
+    "whole_k_ffn_down": (2048, 5760, 2304, (512, 5760, 256)),
 }
 
 
