@@ -53,7 +53,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 #: Flash vs plain prefill: the plain path rounds the softmax probabilities
 #: to bf16, the kernel keeps them in f32. Each application of the shared
 #: attention block added about 5e-3 in CPU runs at reduced widths, and
-#: zamba2-1.2b applies it 6 times.
+#: zamba2-1.2b applies it 6 times. The kernels' prefill also takes each
+#: layer's SSD intra-chunk term through ``ssd_scan``.
 FLASH_TOL = 6e-2
 #: Sharded vs one device: partial sums reduced across chips in bf16; CPU
 #: runs of 2 layers at widths 1024 and 2048 on 4 devices gave 1.3e-2.

@@ -37,8 +37,15 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_groups: int = 1
-    # --- hybrid (zamba2) ---
-    attn_every: int = 0               # shared attn block period (0 = none)
+    # --- hybrid (zamba2): Mamba2 layers; the layers named here also apply
+    # one of ``num_mem_blocks`` parameter-shared attention blocks, in turn,
+    # to concat(hidden, embedding), with an adapter set of its own each ---
+    hybrid_layer_ids: tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0             # LoRA rank of the shared blocks
+    shared_attn_adapter: bool = False  # LoRA on q, k and v
+    shared_mlp_adapter: bool = False   # LoRA on the MLP's gate and up
+    attn_every: int = 0               # retired: give hybrid_layer_ids
     # --- encoder-decoder (seamless) ---
     encoder_layers: int = 0           # 0 -> decoder-only
     # --- frontends (stubbed modalities) ---
@@ -50,11 +57,26 @@ class ModelConfig:
     rope_interleaved: bool = False    # pairs (2i, 2i+1), not rotate-half
     qkv_bias: bool = False            # bias on the q/k/v projections only
     gated_mlp: bool = True
+    mlp_act: str = "silu"             # the gated MLP's gate: silu | gelu
+    attn_scale: float = 0.0           # softmax scale (0 -> 1/sqrt(hd))
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     # --- sharding hints (resolved by sharding/rules.py) ---
     moe_sharding: str = "auto"        # auto | ep | tp
     source: str = ""                  # provenance note
+
+    def __post_init__(self):
+        # a JSON configuration gives the layer ids as a list
+        object.__setattr__(self, "hybrid_layer_ids",
+                           tuple(self.hybrid_layer_ids))
+        if self.attn_every:
+            raise ValueError(f"{self.name}: attn_every is retired; give "
+                             f"hybrid_layer_ids")
+        ids = self.hybrid_layer_ids
+        if ids and (list(ids) != sorted(set(ids)) or ids[0] < 0
+                    or ids[-1] >= self.n_layers):
+            raise ValueError(f"{self.name}: hybrid_layer_ids {ids} must "
+                             f"rise within [0, {self.n_layers})")
 
     @property
     def resolved_head_dim(self) -> int:
@@ -97,9 +119,19 @@ class ModelConfig:
         if self.family == "ssm":
             layers = self.n_layers * (ssm + dense_mlp)
         elif self.family == "hybrid":
-            # mamba blocks per layer; ONE parameter-shared attention block
-            # (with its MLP) reused every `attn_every` layers (Zamba2)
-            layers = self.n_layers * ssm + (attn + dense_mlp)
+            # a Mamba2 mixer in every layer; the shared blocks read
+            # concat(hidden, embedding) and are counted once each; every
+            # application has its own linear and adapters (Zamba2)
+            w, r = 2 * d, self.adapter_rank
+            qkv = hd * (self.n_heads + 2 * self.n_kv_heads)
+            shared = w * qkv + self.n_heads * hd * d + dense_mlp
+            per_app = d * d
+            if self.shared_attn_adapter:
+                per_app += r * (3 * w + qkv)
+            if self.shared_mlp_adapter:
+                per_app += r * (d + 2 * self.d_ff)
+            layers = self.n_layers * ssm + self.num_mem_blocks * shared + \
+                len(self.hybrid_layer_ids) * per_app
         elif self.family == "encdec":
             layers = (self.n_layers + self.encoder_layers) * \
                 (attn + dense_mlp) + self.n_layers * attn  # + cross-attn
@@ -117,14 +149,17 @@ class ModelConfig:
         return self.param_count() - self.n_layers * (total_moe - active_moe)
 
     def reduced(self) -> "ModelConfig":
-        """Small same-family config for CPU smoke tests."""
+        """Small same-family config for CPU smoke tests. A hybrid keeps
+        both its layers hybrid, heads of twice the width over the heads
+        (as Zamba2's are) and rank-8 adapters."""
+        hybrid = bool(self.hybrid_layer_ids)
         return dataclasses.replace(
             self,
             n_layers=2,
             d_model=64,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 4) if self.n_kv_heads else 0,
-            head_dim=16,
+            head_dim=32 if hybrid else 16,
             # the same share of the (now 16-wide) head rotates
             rope_dim=self.rope_dim * 16 // self.resolved_head_dim
             if self.rope_dim else 0,
@@ -136,7 +171,8 @@ class ModelConfig:
             moe_d_ff=32 if self.n_experts else 0,
             ssm_state=16 if self.ssm_state else 0,
             ssm_head_dim=16 if self.ssm_state else 64,
-            attn_every=min(self.attn_every, 2) if self.attn_every else 0,
+            hybrid_layer_ids=(0, 1) if hybrid else (),
+            adapter_rank=8 if self.adapter_rank else 0,
             encoder_layers=2 if self.encoder_layers else 0,
             frontend_seq=8 if self.frontend_seq else 0,
         )

@@ -18,6 +18,7 @@ ARCH_IDS = (
     "mamba2-1.3b",
     "pixtral-12b",
     "zamba2-1.2b",
+    "zamba2-7b",
 )
 
 _MODULES = {a: "repro.configs." + a.replace("-", "_").replace(".", "_")

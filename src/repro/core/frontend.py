@@ -90,6 +90,31 @@ def _ssd_block(prefix: str, cfg: ModelConfig, m: int, *, decode: bool,
                      state=cfg.ssm_state, m=m, decode=decode, count=count)
 
 
+def _shared_blocks(prefix: str, cfg: ModelConfig, m: int, *,
+                   count: int) -> Emitted:
+    """A hybrid's shared-block applications: attention from concat(hidden,
+    embedding) (2 d_model wide) back to d_model, the MLP, each
+    application's linear and its adapters (``a`` into the rank, ``b`` out
+    of it)."""
+    d, hd, r = cfg.d_model, cfg.resolved_head_dim, cfg.adapter_rank
+    out = attn_gemms(prefix, 2 * d, cfg.n_heads, cfg.n_kv_heads, hd, m,
+                     count=count, d_out=d)
+    out += ffn_gemms(prefix, d, cfg.d_ff, m, gated=cfg.gated_mlp,
+                     count=count)
+    out.append((wl.gemm(f"{prefix}.linear", m, d, d), count))
+    adapters = []
+    if cfg.shared_attn_adapter:
+        adapters += [("q", 2 * d, cfg.n_heads * hd),
+                     ("k", 2 * d, cfg.n_kv_heads * hd),
+                     ("v", 2 * d, cfg.n_kv_heads * hd)]
+    if cfg.shared_mlp_adapter:
+        adapters.append(("mlp", d, 2 * cfg.d_ff))
+    for name, d_in, d_out in adapters:
+        out += [(wl.gemm(f"{prefix}.adapter_{name}_a", m, r, d_in), count),
+                (wl.gemm(f"{prefix}.adapter_{name}_b", m, d_out, r), count)]
+    return out
+
+
 def extract_workload(cfg: ModelConfig, spec: ShapeSpec) -> ModelWorkload:
     """Lower ``cfg`` under ``spec`` to the full weight-GEMM workload.
 
@@ -99,9 +124,10 @@ def extract_workload(cfg: ModelConfig, spec: ShapeSpec) -> ModelWorkload:
       moe        per layer: attn + top-k routed expert GEMMs (+ shared
                  experts, + arctic's dense-residual MLP)
       ssm        per layer: SSD block (projections + duality matmuls)
-      hybrid     n_layers SSD blocks + ONE parameter-shared attention+MLP
-                 block *executed* every ``attn_every`` layers (shared
-                 params, repeated compute -> count = n_layers//attn_every)
+      hybrid     n_layers SSD blocks + the parameter-shared attention+MLP
+                 blocks *executed* once per ``hybrid_layer_ids`` entry
+                 (shared params, repeated compute -> count = applications),
+                 with each application's linear and adapters
       encdec     encoder self-attn+FFN over the frontend sequence, decoder
                  self-attn + cross-attn (K/V project the encoder memory;
                  cached at decode) + FFN
@@ -136,12 +162,9 @@ def extract_workload(cfg: ModelConfig, spec: ShapeSpec) -> ModelWorkload:
     elif fam == "hybrid":
         out += _ssd_block(f"{name}.blk", cfg, m, decode=decode,
                           count=cfg.n_layers * inst)
-        n_apply = cfg.n_layers // cfg.attn_every if cfg.attn_every else 0
-        if n_apply:
-            out += _attn_block(f"{name}.shared", cfg, m,
-                               count=n_apply * inst)
-            out += ffn_gemms(f"{name}.shared", cfg.d_model, cfg.d_ff, m,
-                             gated=cfg.gated_mlp, count=n_apply * inst)
+        if cfg.hybrid_layer_ids:
+            out += _shared_blocks(f"{name}.shared", cfg, m, count=len(
+                cfg.hybrid_layer_ids) * inst)
     elif fam == "encdec":
         m_enc = cfg.frontend_seq or m
         if not decode:

@@ -36,8 +36,9 @@ Emitted = list[tuple[wl.Layer, int]]
 
 def attn_gemms(prefix: str, d_model: int, n_heads: int, n_kv_heads: int,
                head_dim: int, m: int, *, kv_m: int | None = None,
-               count: int = 1) -> Emitted:
-    """QKV/O projections with GQA head counts.
+               count: int = 1, d_out: int | None = None) -> Emitted:
+    """QKV/O projections with GQA head counts, from ``d_model`` wide
+    inputs; O projects back to ``d_out`` (default ``d_model``).
 
     Q/O are sized by ``n_heads``; K/V by ``n_kv_heads`` (grouped-query
     attention shrinks the KV projections, e.g. glm4's kv=2 of 32 heads).
@@ -50,8 +51,8 @@ def attn_gemms(prefix: str, d_model: int, n_heads: int, n_kv_heads: int,
     out: Emitted = [         # one flash_attention score/AV op per block
         (wl.gemm(f"{prefix}.wq", m, n_heads * head_dim, d_model, op=att),
          count),
-        (wl.gemm(f"{prefix}.wo", m, d_model, n_heads * head_dim, op=att),
-         count),
+        (wl.gemm(f"{prefix}.wo", m, d_out or d_model, n_heads * head_dim,
+                 op=att), count),
     ]
     if kv_m:
         out += [

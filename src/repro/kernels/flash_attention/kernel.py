@@ -72,12 +72,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "causal",
-                                             "interpret"))
+                                             "sm_scale", "interpret"))
 def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
                        block_q: int = 256, block_k: int = 256,
-                       causal: bool = True,
+                       causal: bool = True, sm_scale: float | None = None,
                        interpret: bool = False) -> jax.Array:
-    """q, k, v: (BH, L, hd) -> (BH, L, hd).
+    """q, k, v: (BH, L, hd) -> (BH, L, hd). ``sm_scale`` multiplies the
+    scores before the softmax (None: ``1/sqrt(hd)``).
 
     The dots run in the precision of the inputs: f32 inputs at HIGHEST
     (the TPU's default passes f32 operands through bf16), bf16 inputs at
@@ -87,7 +88,8 @@ def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_q = min(block_q, lq)
     block_k = min(block_k, lk)
     assert lq % block_q == 0 and lk % block_k == 0
-    sm_scale = 1.0 / math.sqrt(hd)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(hd)
     precision = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 \
         else None
     grid = (bh, lq // block_q, lk // block_k)

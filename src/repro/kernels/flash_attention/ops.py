@@ -22,9 +22,11 @@ def legal_block(l: int, requested: int) -> int:
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 256,
-                    block_k: int = 256, interpret: bool = False) -> jax.Array:
+                    block_k: int = 256, sm_scale: float | None = None,
+                    interpret: bool = False) -> jax.Array:
     """q, k, v: (B, L, H, hd) with H already GQA-expanded. Block sizes are
-    clamped to exact divisors of L (`legal_block`).
+    clamped to exact divisors of L (`legal_block`). ``sm_scale`` is the
+    softmax scale (None: ``1/sqrt(hd)``).
 
     Regions (``repro.tracing``): ``flash_attention.layout`` around the fold
     to (B*H, L, hd) and, apart, around the unfold; ``flash_attention.kernel``
@@ -39,6 +41,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out = flash_attention_bh(qf, kf, vf, causal=causal,
                                  block_q=legal_block(l, block_q),
                                  block_k=legal_block(k.shape[1], block_k),
-                                 interpret=interpret)
+                                 sm_scale=sm_scale, interpret=interpret)
     with region("flash_attention.layout"):
         return out.reshape(b, h, l, hd).transpose(0, 2, 1, 3)

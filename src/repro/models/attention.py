@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import (Identity, apply_rope, dense, init_dense,
-                                 kv_splits)
+                                 kv_splits, lora)
 from repro.tracing import region
 
 
@@ -33,7 +33,9 @@ class KVCache(NamedTuple):
 
 def init_attention(key, d_model: int, n_heads: int, n_kv_heads: int,
                    head_dim: int, dtype=jnp.float32,
-                   qkv_bias: bool = False) -> dict:
+                   qkv_bias: bool = False, d_out: int | None = None) -> dict:
+    """Projections from ``d_model`` wide inputs; the output projection
+    back to ``d_out`` (default ``d_model``)."""
     kq, kk, kv, ko = jax.random.split(key, 4)
     return {
         "wq": init_dense(kq, d_model, n_heads * head_dim, dtype, qkv_bias),
@@ -41,7 +43,7 @@ def init_attention(key, d_model: int, n_heads: int, n_kv_heads: int,
                          qkv_bias),
         "wv": init_dense(kv, d_model, n_kv_heads * head_dim, dtype,
                          qkv_bias),
-        "wo": init_dense(ko, n_heads * head_dim, d_model, dtype),
+        "wo": init_dense(ko, n_heads * head_dim, d_out or d_model, dtype),
     }
 
 
@@ -53,13 +55,18 @@ def _repeat_kv(x: jax.Array, rep: int) -> jax.Array:
                             (b, s, kv, rep, hd)).reshape(b, s, kv * rep, hd)
 
 
+def _scale(hd: int, scale: float | None) -> float:
+    """The softmax scale: ``scale``, or ``1/sqrt(hd)`` where it is None."""
+    return 1.0 / math.sqrt(hd) if scale is None else scale
+
+
 def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   causal: bool, q_offset=None,
-                  kv_length=None) -> jax.Array:
+                  kv_length=None, scale: float | None = None) -> jax.Array:
     """q: (B,Lq,H,hd); k,v: (B,Lk,H,hd). Returns (B,Lq,H,hd)."""
     b, lq, h, hd = q.shape
     lk = k.shape[1]
-    scale = 1.0 / math.sqrt(hd)
+    scale = _scale(hd, scale)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     kpos = jnp.arange(lk)
@@ -80,7 +87,8 @@ def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def dot_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           causal: bool, block_k: int = 1024,
-                          kv_length=None) -> jax.Array:
+                          kv_length=None,
+                          scale: float | None = None) -> jax.Array:
     """Flash-style online-softmax attention expressed in XLA (scan over KV
     blocks, f32 running statistics, bf16 score/prob tensors): the (Lq, Lk)
     f32 score tensor is never materialized — the HBM-traffic reduction the
@@ -93,7 +101,7 @@ def dot_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     while lk % block_k:                 # the largest block that divides
         block_k -= 1
     nb = lk // block_k
-    scale = 1.0 / math.sqrt(hd)
+    scale = _scale(hd, scale)
     kb = k.reshape(bsz, nb, block_k, h, hd)
     vb = v.reshape(bsz, nb, block_k, h, hd)
     qpos = jnp.arange(lq)
@@ -133,7 +141,8 @@ def dot_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def grouped_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                              kv_length: jax.Array, *, splits: int = 1,
-                             shard=Identity) -> jax.Array:
+                             shard=Identity,
+                             scale: float | None = None) -> jax.Array:
     """One query position against a cache, query heads grouped by the KV
     head they share. q: (B, 1, H, hd); k, v: (B, S, KV, hd); positions at
     and past ``kv_length`` (B,) are masked. Returns (B, 1, H, hd).
@@ -155,7 +164,7 @@ def grouped_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     with region("kv_attend"):
         scores = jnp.einsum("bgrd,bnsgd->bngrs", qg, kb,
                             preferred_element_type=jnp.float32)
-        scores = scores * (1.0 / math.sqrt(hd))
+        scores = scores * _scale(hd, scale)
         kpos = jnp.arange(s_len).reshape(n, s_len // n)
         valid = kpos[None] < kv_length[:, None, None]          # (B, n, s)
         scores = jnp.where(valid[:, :, None, None, :], scores, neg)
@@ -216,7 +225,8 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
               positions: jax.Array | None = None,
               cache: KVCache | None = None, layer: jax.Array | None = None,
               shard=Identity, use_flash: bool = False,
-              rope_dim: int = 0, rope_interleaved: bool = False):
+              rope_dim: int = 0, rope_interleaved: bool = False,
+              scale: float | None = None, adapters: dict | None = None):
     """Returns (out, new_cache). Prefill: cache=None, full seq; new_cache
     holds the sequence's K/V. Decode: x is (B, 1, D) and cache holds past
     K/V, either one layer's (``layer`` None) or the whole layer stack
@@ -227,13 +237,24 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
     ``length``; a write past the cache's end is dropped while ``length``
     still rises. It then attends over the positions before
     ``length + 1``, and returns the cache it was given with those writes.
-    Named scopes (``repro.tracing``): ``attn`` around it all, ``rope``
-    around the rotary, ``kv_update`` around the cache write, and
-    ``grouped_decode_attention``'s."""
+    ``scale`` is the softmax scale (None: ``1/sqrt(head_dim)``).
+    ``adapters`` maps any of ``q``, ``k``, ``v`` to a low-rank adapter
+    whose output is added to that projection.
+    Named scopes (``repro.tracing``): ``attn`` around it all, ``adapter``
+    around the adapters, ``rope`` around the rotary, ``kv_update`` around
+    the cache write, and ``grouped_decode_attention``'s."""
     b, l, _ = x.shape
-    q = dense(params["wq"], x).reshape(b, l, n_heads, head_dim)
-    k = dense(params["wk"], x).reshape(b, l, n_kv_heads, head_dim)
-    v = dense(params["wv"], x).reshape(b, l, n_kv_heads, head_dim)
+
+    def project(name, heads):
+        t = dense(params["w" + name], x)
+        if adapters and name in adapters:
+            with region("adapter"):
+                t = t + lora(adapters[name], x)
+        return t.reshape(b, l, heads, head_dim)
+
+    q = project("q", n_heads)
+    k = project("k", n_kv_heads)
+    v = project("v", n_kv_heads)
     q = shard("attn_q", q)
     rep = n_heads // n_kv_heads
 
@@ -248,11 +269,12 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
         kf, vf = _repeat_kv(k, rep), _repeat_kv(v, rep)
         if use_flash and causal and l >= 512:
             from repro.kernels.flash_attention.ops import flash_attention
-            out = flash_attention(q, kf, vf, causal=True)
+            out = flash_attention(q, kf, vf, causal=True, sm_scale=scale)
         elif l >= 2048:
-            out = dot_attention_chunked(q, kf, vf, causal=causal)
+            out = dot_attention_chunked(q, kf, vf, causal=causal,
+                                        scale=scale)
         else:
-            out = dot_attention(q, kf, vf, causal=causal)
+            out = dot_attention(q, kf, vf, causal=causal, scale=scale)
         new_cache = KVCache(k=k, v=v, length=jnp.full((b,), l, jnp.int32))
     else:
         # single-token decode against the cache: write the new position,
@@ -283,10 +305,11 @@ def attention(params: dict, x: jax.Array, *, n_heads: int, n_kv_heads: int,
         kf, vf = this_layer(new_cache.k), this_layer(new_cache.v)
         if rep == 1 and splits == 1:
             out = dot_attention(q, kf, vf, causal=False,
-                                kv_length=length + 1)
+                                kv_length=length + 1, scale=scale)
         else:
             out = grouped_decode_attention(q, kf, vf, length + 1,
-                                           splits=splits, shard=shard)
+                                           splits=splits, shard=shard,
+                                           scale=scale)
     out = shard("attn_out", out)
     out = out.reshape(b, l, n_heads * head_dim)
     return dense(params["wo"], out), new_cache

@@ -89,14 +89,38 @@ def init_mlp(key, d_model: int, d_ff: int, gated: bool,
     return p
 
 
-def mlp(params: dict, x: jax.Array, gated: bool, shard=Identity) -> jax.Array:
+def mlp(params: dict, x: jax.Array, gated: bool, shard=Identity, *,
+        act: str = "silu", gate_up_delta=None) -> jax.Array:
+    """``down(act(gate x) * up x)`` where ``gated``, else ``down(gelu(up
+    x))``. ``act`` is the gate's activation: ``silu`` or ``gelu`` (the
+    exact, erf form). ``gate_up_delta`` (gate, up) is added to the two
+    projections before the gate (an adapter's output)."""
     h = dense(params["up"], x)
     if gated:
-        h = jax.nn.silu(dense(params["gate"], x)) * h
+        g = dense(params["gate"], x)
+        if gate_up_delta is not None:
+            g, h = g + gate_up_delta[0], h + gate_up_delta[1]
+        if act == "gelu":
+            h = jax.nn.gelu(g, approximate=False) * h
+        else:
+            h = jax.nn.silu(g) * h
     else:
         h = jax.nn.gelu(h)
     h = shard("ffn_hidden", h)
     return dense(params["down"], h)
+
+
+def init_lora(key, d_in: int, d_out: int, rank: int,
+              dtype=jnp.float32) -> dict:
+    """A low-rank adapter ``b(a(x))``: ``a`` (d_in, rank), ``b`` (rank,
+    d_out)."""
+    ka, kb = jax.random.split(key)
+    return {"a": init_dense(ka, d_in, rank, dtype),
+            "b": init_dense(kb, rank, d_out, dtype)}
+
+
+def lora(params: dict, x: jax.Array) -> jax.Array:
+    return dense(params["b"], dense(params["a"], x))
 
 
 # ---------------------------------------------------------------------------

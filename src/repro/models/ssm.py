@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import (Identity, dense, init_dense, init_rmsnorm,
-                                 rms_norm, truncated_normal)
+                                 truncated_normal)
+from repro.tracing import region
 
 
 class SSMState(NamedTuple):
@@ -130,17 +131,33 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 
 
 def ssd_recurrent_step(x, dt, a, b, c, d_skip, h):
-    """O(1) decode update. x:(B,H,P) dt:(B,H) b,c:(B,G,N) h:(B,H,P,N)."""
+    """O(1) decode update. x:(B,H,P) dt:(B,H) b,c:(B,G,N) h:(B,H,P,N).
+
+    Elementwise products and a sum, in float32, not dots: the state is
+    read and written once either way, and these leave XLA free to keep
+    a stacked state in the layout the step receives it in."""
     bsz, nh, p = x.shape
     g = b.shape[1]
     rep = nh // g
-    bb = jnp.repeat(b, rep, axis=1)                 # (B,H,N)
-    cc = jnp.repeat(c, rep, axis=1)
+    f32 = jnp.float32
+    bb = jnp.repeat(b, rep, axis=1).astype(f32)     # (B,H,N)
+    cc = jnp.repeat(c, rep, axis=1).astype(f32)
     dec = jnp.exp(dt * a)                           # (B,H)
-    upd = jnp.einsum("bh,bhn,bhp->bhpn", dt, bb, x)
-    h_new = h * dec[:, :, None, None] + upd.astype(jnp.float32)
-    y = jnp.einsum("bhpn,bhn->bhp", h_new.astype(x.dtype), cc)
+    upd = (dt[..., None] * x.astype(f32))[..., None] * bb[:, :, None, :]
+    h_new = h * dec[:, :, None, None] + upd
+    y = jnp.sum(h_new * cc[:, :, None, :], axis=-1).astype(x.dtype)
     return y + x * d_skip[None, :, None].astype(x.dtype), h_new
+
+
+def gated_rms_norm(params: dict, y: jax.Array, z: jax.Array, groups: int,
+                   eps: float = 1e-5) -> jax.Array:
+    """``y * silu(z)`` normalised to unit RMS over each of ``groups`` equal
+    groups of its last axis, in float32, then scaled; in ``y``'s dtype."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    parts = g.reshape(g.shape[:-1] + (groups, g.shape[-1] // groups))
+    var = jnp.mean(jnp.square(parts), axis=-1, keepdims=True)
+    g = (parts * jax.lax.rsqrt(var + eps)).reshape(g.shape)
+    return (g * params["scale"]).astype(y.dtype)
 
 
 def _causal_conv(xbc: jax.Array, w: jax.Array, bias: jax.Array,
@@ -160,11 +177,16 @@ def _causal_conv(xbc: jax.Array, w: jax.Array, bias: jax.Array,
     return jax.nn.silu(out), new_state
 
 
+@region("mamba")
 def mamba2_block(params: dict, x: jax.Array, cfg, *,
                  state: SSMState | None = None, chunk: int = 256,
                  shard=Identity, use_kernel: bool = False):
     """x: (B, L, D) (prefill/train) or (B, 1, D) with state (decode).
-    Returns (out, new_state)."""
+    Returns (out, new_state). ``use_kernel`` runs the chunked scan's
+    intra-chunk term in the ``ssd_scan`` Pallas kernel. Named scopes
+    (``repro.tracing``): ``mamba`` around it all, ``conv`` around the
+    causal convolution, ``ssd`` around the chunked scan or the recurrent
+    update."""
     d_inner, n_heads, conv_dim = ssd_dims(
         x.shape[-1], cfg.ssm_expand, cfg.ssm_head_dim, cfg.ssm_groups,
         cfg.ssm_state)
@@ -178,8 +200,9 @@ def mamba2_block(params: dict, x: jax.Array, cfg, *,
 
     decode = state is not None and l == 1
     conv_state = state.conv if state is not None else None
-    xbc, new_conv = _causal_conv(xbc, params["conv_w"], params["conv_b"],
-                                 conv_state)
+    with region("conv"):
+        xbc, new_conv = _causal_conv(xbc, params["conv_w"],
+                                     params["conv_b"], conv_state)
     xs = xbc[..., :d_inner].reshape(bsz, l, n_heads, cfg.ssm_head_dim)
     bmat = xbc[..., d_inner:d_inner + gn].reshape(
         bsz, l, cfg.ssm_groups, cfg.ssm_state)
@@ -187,25 +210,26 @@ def mamba2_block(params: dict, x: jax.Array, cfg, *,
         bsz, l, cfg.ssm_groups, cfg.ssm_state)
     xs = shard("ssm_x", xs)
 
-    if decode:
-        y, h_new = ssd_recurrent_step(
-            xs[:, 0], dt[:, 0], a, bmat[:, 0], cmat[:, 0],
-            params["d_skip"], state.h)
-        y = y[:, None]
-    else:
-        h0 = state.h if state is not None else None
-        pad_to = (-l) % chunk
-        if pad_to:
-            padc = lambda t: jnp.pad(t, [(0, 0), (0, pad_to)] +
-                                     [(0, 0)] * (t.ndim - 2))
-            xs, dt = padc(xs), padc(dt)
-            bmat, cmat = padc(bmat), padc(cmat)
-        y, h_new = ssd_chunked(xs, dt, a, bmat, cmat, params["d_skip"],
-                               chunk=min(chunk, xs.shape[1]), h0=h0,
-                               use_kernel=use_kernel)
-        y = y[:, :l]
+    with region("ssd"):
+        if decode:
+            y, h_new = ssd_recurrent_step(
+                xs[:, 0], dt[:, 0], a, bmat[:, 0], cmat[:, 0],
+                params["d_skip"], state.h)
+            y = y[:, None]
+        else:
+            h0 = state.h if state is not None else None
+            pad_to = (-l) % chunk
+            if pad_to:
+                padc = lambda t: jnp.pad(t, [(0, 0), (0, pad_to)] +
+                                         [(0, 0)] * (t.ndim - 2))
+                xs, dt = padc(xs), padc(dt)
+                bmat, cmat = padc(bmat), padc(cmat)
+            y, h_new = ssd_chunked(xs, dt, a, bmat, cmat, params["d_skip"],
+                                   chunk=min(chunk, xs.shape[1]), h0=h0,
+                                   use_kernel=use_kernel)
+            y = y[:, :l]
     y = y.reshape(bsz, l, d_inner)
-    y = rms_norm(params["norm"], y * jax.nn.silu(z.astype(y.dtype)))
+    y = gated_rms_norm(params["norm"], y, z, cfg.ssm_groups)
     out = dense(params["out_proj"], y)
     return out, SSMState(h=h_new, conv=new_conv)
 

@@ -4,19 +4,25 @@ One ``init_model`` / ``forward`` pair covers:
   dense / vlm  : pre-norm GQA blocks + (gated) MLP        (scan over layers)
   moe          : GQA blocks + routed experts (+ shared / dense-residual)
   ssm          : Mamba2 (SSD) blocks, attention-free
-  hybrid       : Mamba2 backbone + parameter-shared attention block every
-                 ``attn_every`` layers (Zamba2)
+  hybrid       : Mamba2 layers; each of ``hybrid_layer_ids`` first applies
+                 one of ``num_mem_blocks`` parameter-shared transformer
+                 blocks, in turn, to concat(hidden, embedding), and adds
+                 its output, through a linear of its own, to that layer's
+                 Mamba input (Zamba2)
   encdec       : bidirectional encoder + causal decoder w/ cross-attention
                  (Seamless backbone; audio frontend stubbed)
 
 Layers are stacked and driven by ``jax.lax.scan`` (small HLO, fast
-compile); training wraps the block in ``jax.checkpoint``. Modes:
+compile), but for the hybrid's, which are unrolled (``_hybrid_stack``);
+training wraps the block in ``jax.checkpoint``. Modes:
   "train"   tokens -> logits                  (full seq, causal)
   "prefill" tokens -> logits + caches         (the scan's ys)
   "decode"  one token + caches -> logits + caches
 Decode of the attention stacks carries the layer-stacked KV cache through
 the scan and writes each layer's one new position into it in place; no
-layer's cache is sliced out, rebuilt or stacked back.
+layer's cache is sliced out, rebuilt or stacked back. The hybrid updates
+its stacked SSM states and its KV cache (one entry per application) in
+place the same way.
 """
 
 from __future__ import annotations
@@ -29,10 +35,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.attention import (attention, init_attention,
-                                    init_kv_cache)
-from repro.models.layers import (Identity, embed, init_embedding, init_mlp,
-                                 init_rmsnorm, mlp, rms_norm, unembed)
+from repro.models.attention import attention, init_attention
+from repro.models.layers import (Identity, dense, embed, init_dense,
+                                 init_embedding, init_lora, init_mlp,
+                                 init_rmsnorm, lora, mlp, rms_norm, unembed)
 from repro.models.moe import init_moe, moe
 from repro.models.ssm import (SSMState, init_mamba2, init_ssm_state,
                               mamba2_block)
@@ -78,6 +84,39 @@ def _init_mamba_block(key, cfg: ModelConfig, dtype) -> dict:
     }
 
 
+def _init_shared_block(key, cfg: ModelConfig, dtype) -> dict:
+    """A hybrid's parameter-shared transformer block: attention from
+    concat(hidden, embedding), twice ``d_model`` wide, back to
+    ``d_model``, then the (gated) MLP."""
+    k1, k2 = jax.random.split(key)
+    d = cfg.d_model
+    return {
+        "ln_in": init_rmsnorm(2 * d),
+        "attn": init_attention(k1, 2 * d, cfg.n_heads, cfg.n_kv_heads,
+                               cfg.resolved_head_dim, dtype, cfg.qkv_bias,
+                               d_out=d),
+        "ln_ff": init_rmsnorm(d),
+        "mlp": init_mlp(k2, d, cfg.d_ff, cfg.gated_mlp, dtype),
+    }
+
+
+def _init_application(key, cfg: ModelConfig, dtype) -> dict:
+    """What one application of a shared block holds alone: the linear
+    into its layer's Mamba input, and its adapters."""
+    kl, kq, kk, kv, km = jax.random.split(key, 5)
+    d, hd, r = cfg.d_model, cfg.resolved_head_dim, cfg.adapter_rank
+    p = {"linear": init_dense(kl, d, d, dtype)}
+    if cfg.shared_attn_adapter:
+        p["attn_adapters"] = {
+            n: init_lora(k, 2 * d, heads * hd, r, dtype)
+            for n, k, heads in (("q", kq, cfg.n_heads),
+                                ("k", kk, cfg.n_kv_heads),
+                                ("v", kv, cfg.n_kv_heads))}
+    if cfg.shared_mlp_adapter:
+        p["mlp_adapter"] = init_lora(km, d, 2 * cfg.d_ff, r, dtype)
+    return p
+
+
 def _init_cross_block(key, cfg: ModelConfig, dtype) -> dict:
     """Decoder block with cross-attention (encdec family)."""
     p = _init_attn_block(key, cfg, dtype)
@@ -106,15 +145,15 @@ def init_model(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
         params["blocks"] = _stack_init(
             lambda k: _init_mamba_block(k, cfg, dtype), kb, cfg.n_layers)
     elif fam == "hybrid":
-        n_groups = cfg.n_layers // cfg.attn_every
-        grouped = n_groups * cfg.attn_every
         params["blocks"] = _stack_init(
-            lambda k: _init_mamba_block(k, cfg, dtype), kb, grouped)
-        params["tail"] = _stack_init(
-            lambda k: _init_mamba_block(k, cfg, dtype),
-            jax.random.fold_in(kb, 3), cfg.n_layers - grouped) \
-            if cfg.n_layers - grouped else None
-        params["shared_attn"] = _init_attn_block(ks, cfg, dtype)
+            lambda k: _init_mamba_block(k, cfg, dtype), kb, cfg.n_layers)
+        if cfg.hybrid_layer_ids:
+            params["shared_blocks"] = _stack_init(
+                lambda k: _init_shared_block(k, cfg, dtype), ks,
+                cfg.num_mem_blocks)
+            params["app_blocks"] = _stack_init(
+                lambda k: _init_application(k, cfg, dtype),
+                jax.random.fold_in(ks, 1), len(cfg.hybrid_layer_ids))
     elif fam == "encdec":
         params["enc_blocks"] = _stack_init(
             lambda k: _init_attn_block(k, cfg, dtype), kb, cfg.encoder_layers)
@@ -124,8 +163,6 @@ def init_model(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
         params["ln_enc"] = init_rmsnorm(cfg.d_model)
     else:
         raise ValueError(fam)
-    if fam in ("hybrid",) and params.get("tail") is None:
-        params.pop("tail")
     return params
 
 
@@ -243,10 +280,10 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
                                         shard, remat and mode == "train",
                                         is_decode)
     elif fam == "hybrid":
-        x, new_caches, aux = _hybrid_stack(params, x, cfg, caches, shard,
-                                           remat and mode == "train",
-                                           is_decode, compute_dtype,
-                                           use_flash)
+        x, new_caches = _hybrid_stack(params, x, cfg, caches, mode=mode,
+                                      shard=shard, use_flash=use_flash,
+                                      remat=remat and mode == "train",
+                                      compute_dtype=compute_dtype)
     elif fam == "encdec":
         x, new_caches, aux = _encdec_stack(params, x, cfg, caches,
                                            frontend_embeds, shard,
@@ -292,68 +329,96 @@ def _ssm_stack(stack, x, cfg, states, shard, remat, is_decode):
     return x, new_states, jnp.zeros((), jnp.float32)
 
 
-def _hybrid_stack(params, x, cfg, caches, shard, remat, is_decode,
-                  compute_dtype, use_flash):
+def _shared_block(params, app, x, emb, cfg, kv, *, shard, use_flash):
+    """Application ``app`` of shared block ``app % num_mem_blocks`` to
+    (x, emb): ``linear(down(act(g) * u))`` with ``(g, u) = gate_up(h) +
+    adapter(h)``, ``h = rms_norm(o_proj(attn(rms_norm(concat(x,
+    emb)))))``, no residual inside. ``kv``, in decode, is the KV cache
+    stacked over applications, in which the attention writes its new
+    position at entry ``app`` in place. Returns (out, the cache ``kv``
+    with that write, or this application's fresh one without ``kv``)."""
+    blk = jax.tree.map(lambda t: t[app % cfg.num_mem_blocks],
+                       params["shared_blocks"])
+    mine = jax.tree.map(lambda t: t[app], params["app_blocks"])
+    h = rms_norm(blk["ln_in"], jnp.concatenate([x, emb], axis=-1),
+                 cfg.norm_eps)
+    a, cache = attention(
+        blk["attn"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+        head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
+        cache=kv, layer=app, shard=shard, use_flash=use_flash,
+        rope_dim=cfg.rope_dim, rope_interleaved=cfg.rope_interleaved,
+        scale=cfg.attn_scale or None, adapters=mine.get("attn_adapters"))
+    h = rms_norm(blk["ln_ff"], a, cfg.norm_eps)
+    with region("mlp"):
+        delta = None
+        if "mlp_adapter" in mine:
+            with region("adapter"):
+                gu = lora(mine["mlp_adapter"], h)
+            delta = (gu[..., :cfg.d_ff], gu[..., cfg.d_ff:])
+        out = mlp(blk["mlp"], h, cfg.gated_mlp, shard, act=cfg.mlp_act,
+                  gate_up_delta=delta)
+    return dense(mine["linear"], out), cache
+
+
+def _hybrid_stack(params, x, cfg, caches, *, mode, shard, use_flash, remat,
+                  compute_dtype):
+    """Zamba2's layers: for each layer i, ``x += mamba_i(rms_norm_i(x +
+    t_i))``, where ``t_i`` is ``_shared_block``'s output on a hybrid
+    layer and 0 elsewhere. ``caches`` (decode): (SSMState stacked over
+    layers, KVCache stacked over applications); each layer updates its
+    own entry of the states, and each application its own entry of the
+    cache, in place. Prefill returns them fresh; training returns None.
+
+    The layers are unrolled, not scanned: a scan cannot place a shared
+    block between two of its steps without a ``cond``, and across a
+    ``cond`` or from one scan to the next XLA changes the layout of the
+    carried cache or states and copies the whole of them. Named scopes
+    (``repro.tracing``): ``shared_block`` around each application, with
+    ``attn``, ``rope``, ``kv_update``, ``mlp`` and ``adapter`` inside;
+    ``mamba``, ``conv`` and ``ssd`` in each layer."""
     b = x.shape[0]
-    n_groups = cfg.n_layers // cfg.attn_every
-    grouped = n_groups * cfg.attn_every
-    tail_n = cfg.n_layers - grouped
-    if caches is None:
-        proto = init_ssm_state(b, cfg, cfg.d_model)
-        ssm_states = jax.tree.map(
+    ids = cfg.hybrid_layer_ids
+    is_decode = mode == "decode"
+    emb = x
+    if is_decode:
+        states, kv = caches
+    elif mode == "prefill":
+        proto = init_ssm_state(b, cfg, cfg.d_model, compute_dtype)
+        states = jax.tree.map(
             lambda t: jnp.zeros((cfg.n_layers,) + t.shape, t.dtype), proto)
         kv = None
-    else:
-        ssm_states, kv = caches
-    max_seq = x.shape[1] if kv is None else kv.k.shape[2]
-    main_states = jax.tree.map(lambda t: t[:grouped], ssm_states)
-    grouped_states = jax.tree.map(
-        lambda t: t.reshape((n_groups, cfg.attn_every) + t.shape[1:]),
-        main_states)
+    else:                               # training keeps no state
+        states = kv = None
+    fresh = []                          # prefill: each application's cache
 
-    def mamba_body(carry, layer_in):
-        x = carry
-        blk, st = layer_in
+    def layer(blk, x, st):
         h = rms_norm(blk["ln"], x, cfg.norm_eps)
-        out, new_st = mamba2_block(blk["mamba"], h, cfg,
-                                   state=st if is_decode else None,
-                                   shard=shard)
-        return x + out, new_st
+        return mamba2_block(blk["mamba"], h, cfg, state=st, shard=shard,
+                            use_kernel=use_flash)
 
-    mamba_fn = jax.checkpoint(mamba_body) if remat else mamba_body
-    grouped_params = jax.tree.map(
-        lambda t: t.reshape((n_groups, cfg.attn_every) + t.shape[1:]),
-        params["blocks"])
-
-    def group_body(carry, layer_in):
-        x = carry
-        blocks_g, states_g, kv_g = layer_in
-        x, new_states_g = jax.lax.scan(mamba_fn, x, (blocks_g, states_g))
-        # parameter-shared attention block
-        x, new_kv, aux, _ = _attn_block_apply(
-            params["shared_attn"], x, cfg,
-            kv_g if is_decode else None, causal=True, shard=shard,
-            use_flash=use_flash)
-        return x, (new_states_g, new_kv)
-
-    if kv is None:
-        kv_stack = init_kv_cache(n_groups, b, max_seq, cfg.n_kv_heads,
-                                 cfg.resolved_head_dim, compute_dtype)
-    else:
-        kv_stack = kv
-    x, (new_grouped_states, new_kv_stack) = jax.lax.scan(
-        group_body, x, (grouped_params, grouped_states, kv_stack))
-    new_main = jax.tree.map(
-        lambda t: t.reshape((grouped,) + t.shape[2:]), new_grouped_states)
-    if tail_n:
-        tail_states = jax.tree.map(lambda t: t[grouped:], ssm_states)
-        x, new_tail = jax.lax.scan(mamba_fn, x,
-                                   (params["tail"], tail_states))
-        new_states = jax.tree.map(
-            lambda a, c: jnp.concatenate([a, c], axis=0), new_main, new_tail)
-    else:
-        new_states = new_main
-    return x, (new_states, new_kv_stack), jnp.zeros((), jnp.float32)
+    fn = jax.checkpoint(layer) if remat else layer
+    for i in range(cfg.n_layers):
+        x_in = x
+        if i in ids:
+            with region("shared_block"):
+                t, cache = _shared_block(params, ids.index(i), x, emb, cfg,
+                                         kv, shard=shard,
+                                         use_flash=use_flash)
+            kv = cache if is_decode else None
+            fresh.append(cache)
+            x_in = x + t
+        blk = jax.tree.map(lambda w: w[i], params["blocks"])
+        st = jax.tree.map(lambda s: s[i], states) if is_decode else None
+        out, new_st = fn(blk, x_in, st)
+        if states is not None:
+            states = jax.tree.map(
+                lambda s, n: s.at[i].set(n.astype(s.dtype)), states, new_st)
+        x = x + out
+    if mode == "train":
+        return x, None
+    if not is_decode and fresh:
+        kv = jax.tree.map(lambda *t: jnp.stack(t), *fresh)
+    return x, (states, kv)
 
 
 def _encdec_stack(params, x, cfg, caches, frontend_embeds, shard, remat,

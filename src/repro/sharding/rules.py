@@ -49,19 +49,15 @@ class ShardingPlan:
 
     # ---- parameter specs ---------------------------------------------------
     def param_spec(self, path: tuple[str, ...], leaf: Any) -> P:
-        """Spec for one parameter leaf. Layer-stacked subtrees (scan-over-
-        layers: 'blocks', 'enc_blocks', 'tail') carry a leading layer axis
-        that is never sharded — the logical rule applies to the remaining
-        dims."""
+        """Spec for one parameter leaf. Stacked subtrees ('blocks',
+        'enc_blocks', and the hybrid's 'shared_blocks' and 'app_blocks')
+        carry a leading axis of layers, blocks or applications that is
+        never sharded — the logical rule applies to the remaining dims."""
         name = "/".join(str(p) for p in path)
         nd = leaf.ndim if hasattr(leaf, "ndim") else 0
         model = self.model_axis
         data = "data"
-        stacked = any(seg in name for seg in ("blocks", "tail/"))
-        if "tail" in name.split("/"):
-            stacked = True
-        if "shared_attn" in name:
-            stacked = False
+        stacked = "blocks" in name
         end = nd - (1 if stacked else 0)   # effective (logical) rank
 
         def wrap(*spec_dims):

@@ -38,6 +38,8 @@ class TrainState(NamedTuple):
 class StepConfig:
     microbatches: int = 1
     remat: bool = True
+    #: the Pallas kernels in the step: flash attention for prompts of 512
+    #: or more, and ``ssd_scan`` for the chunked SSD's intra-chunk term
     use_flash: bool = False
     compress_pod_grads: bool = False
     compute_dtype: Any = jnp.bfloat16
@@ -178,11 +180,12 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int,
         return jax.tree.map(
             lambda t: jnp.zeros((cfg.n_layers,) + t.shape, t.dtype), st)
     if fam == "hybrid":
-        st = init_ssm_state(batch, cfg, cfg.d_model)
+        st = init_ssm_state(batch, cfg, cfg.d_model, compute_dtype)
         states = jax.tree.map(
             lambda t: jnp.zeros((cfg.n_layers,) + t.shape, t.dtype), st)
-        kv = init_kv_cache(cfg.n_layers // cfg.attn_every, batch, max_seq,
-                           cfg.n_kv_heads, hd, compute_dtype)
+        kv = init_kv_cache(len(cfg.hybrid_layer_ids), batch, max_seq,
+                           cfg.n_kv_heads, hd, compute_dtype) \
+            if cfg.hybrid_layer_ids else None
         return (states, kv)
     if fam == "encdec":
         kv = init_kv_cache(cfg.n_layers, batch, max_seq, cfg.n_kv_heads, hd,

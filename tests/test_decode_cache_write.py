@@ -155,7 +155,8 @@ CACHE_CONFIGS = ["minicpm-2b", "qwen2-moe-a2.7b", "zamba2-1.2b",
 @pytest.mark.parametrize("name", CACHE_CONFIGS)
 def test_init_caches_shapes(name):
     """Every family's decode caches hold one empty ``KVCache`` stacked
-    over its attention layers (the hybrid's over its groups of layers):
+    over its attention layers (the hybrid's over its shared-block
+    applications):
     K and V of (layers, batch, max_seq, KV heads, head dim) in the
     requested dtype, lengths of 0, and no scales."""
     cfg = get_config(name).reduced()
@@ -166,7 +167,7 @@ def test_init_caches_shapes(name):
         if isinstance(c, KVCache)]
     assert len(kvs) == 1
     kv, = kvs
-    layers = cfg.n_layers // cfg.attn_every if cfg.family == "hybrid" \
+    layers = len(cfg.hybrid_layer_ids) if cfg.family == "hybrid" \
         else cfg.n_layers
     shape = (layers, batch, max_seq, cfg.n_kv_heads, cfg.resolved_head_dim)
     assert kv.k.shape == kv.v.shape == shape

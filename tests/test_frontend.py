@@ -162,14 +162,60 @@ def test_ssd_decode_is_rank1_state_update():
     assert c == cfg.n_layers * DECODE.global_batch * nh
 
 
+#: The lowered GEMM of each shared-block weight: its name's suffix, and the
+#: path of the ``init_model`` parameter (in, out) it multiplies by.
+SHARED_GEMMS = {
+    "shared.wq": ("shared_blocks", "attn", "wq"),
+    "shared.wk": ("shared_blocks", "attn", "wk"),
+    "shared.wv": ("shared_blocks", "attn", "wv"),
+    "shared.wo": ("shared_blocks", "attn", "wo"),
+    "shared.ffn_down": ("shared_blocks", "mlp", "down"),
+    "shared.linear": ("app_blocks", "linear"),
+    "shared.adapter_q_a": ("app_blocks", "attn_adapters", "q", "a"),
+    "shared.adapter_q_b": ("app_blocks", "attn_adapters", "q", "b"),
+    "shared.adapter_k_a": ("app_blocks", "attn_adapters", "k", "a"),
+    "shared.adapter_k_b": ("app_blocks", "attn_adapters", "k", "b"),
+    "shared.adapter_v_a": ("app_blocks", "attn_adapters", "v", "a"),
+    "shared.adapter_v_b": ("app_blocks", "attn_adapters", "v", "b"),
+    "shared.adapter_mlp_a": ("app_blocks", "mlp_adapter", "a"),
+    "shared.adapter_mlp_b": ("app_blocks", "mlp_adapter", "b"),
+}
+
+
 def test_hybrid_shared_attention_multiplicity():
-    """Zamba2's attention block is parameter-shared but *executed* every
-    ``attn_every`` mamba blocks — its count is applications, not layers."""
+    """Zamba2's shared blocks are parameter-shared but *executed* once per
+    hybrid layer: every shared-block GEMM counts applications, not layers
+    or blocks; each is the shape of the weight ``init_model`` gives it
+    (QKV from concat(hidden, embedding), O back to d_model, the fused
+    gate and up, the per-application linear and both adapter kinds); and
+    every layer has an SSD block."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models.transformer import init_model
     cfg = get_config("zamba2-1.2b")
+    assert cfg.shared_attn_adapter and cfg.shared_mlp_adapter
+    params = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(0), cfg,
+                                               jnp.bfloat16))
     work = extract_workload(cfg, PREFILL)
-    (_, c), = _layers_named(work, "shared.wq")
-    assert c == (cfg.n_layers // cfg.attn_every) * PREFILL.instance_count
-    assert any(".in_proj" in l.name for l in work.layers)
+    apps = len(cfg.hybrid_layer_ids) * PREFILL.instance_count
+    for suffix, path in SHARED_GEMMS.items():
+        (l, c), = _layers_named(work, suffix)
+        w = params
+        for k in path:
+            w = w[k]
+        w = w["w"]
+        lead = cfg.num_mem_blocks if path[0] == "shared_blocks" else \
+            len(cfg.hybrid_layer_ids)
+        assert w.shape == (lead, l.bound("C"), l.bound("K")), suffix
+        assert c == apps, suffix
+    (up, c), = _layers_named(work, "shared.ffn_up")
+    mlp = params["shared_blocks"]["mlp"]
+    assert (up.bound("C"), up.bound("K")) == (
+        cfg.d_model, mlp["gate"]["w"].shape[-1] + mlp["up"]["w"].shape[-1])
+    assert c == apps
+    (_, c), = _layers_named(work, ".in_proj")
+    assert c == cfg.n_layers * PREFILL.instance_count
 
 
 # ---------------------------------------------------------------------------

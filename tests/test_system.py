@@ -28,7 +28,7 @@ def test_miredo_end_to_end_beats_baselines():
 
 def test_registry_complete():
     cfgs = all_configs()
-    assert len(cfgs) == 10
+    assert len(cfgs) == 11
     for arch_id in ARCH_IDS:
         cfg = cfgs[arch_id]
         assert cfg.param_count() > 1e8, arch_id
@@ -52,7 +52,7 @@ def test_param_counts_match_public_figures():
         "internlm2-20b": 20e9, "glm4-9b": 9.4e9, "starcoder2-7b": 7.2e9,
         "minicpm-2b": 2.4e9, "qwen2-moe-a2.7b": 14.3e9,
         "arctic-480b": 482e9, "mamba2-1.3b": 1.3e9,
-        "pixtral-12b": 12e9, "zamba2-1.2b": 1.2e9,
+        "pixtral-12b": 12e9, "zamba2-1.2b": 1.2e9, "zamba2-7b": 7.4e9,
     }
     cfgs = all_configs()
     for arch_id, target in expected.items():

@@ -128,3 +128,50 @@ def test_decode_step_writes_the_cache_in_place(one_chip):
         params, {"tokens": tok}, caches).compile()
     layer_k = caches.k.size * caches.k.dtype.itemsize // cfg.n_layers
     assert compiled.memory_analysis().temp_size_in_bytes < layer_k
+
+
+def test_zamba2_kernels_compile(one_chip):
+    """zamba2-7b's prefill at 1 x 4096 in bf16: flash at heads of 224 with
+    the scale (224 / 2) ** -0.5, and the SSD's intra-chunk term over 16
+    chunks of 256 and 112 heads (state 64, head dim 64)."""
+    qkv = ((1, 4096, 32, 224), jnp.bfloat16)
+    fn = functools.partial(flash_attention, causal=True,
+                           sm_scale=112 ** -0.5)
+    _compile(fn, one_chip, qkv, qkv, qkv)
+    big = (1, 16, 256, 112)
+    _compile(ssd_intra_chunk, one_chip, (big + (64,), jnp.bfloat16),
+             (big + (64,), jnp.bfloat16), (big, jnp.float32),
+             (big, jnp.float32), (big + (64,), jnp.bfloat16))
+
+
+def test_zamba2_decode_step_updates_its_caches_in_place(one_chip):
+    """zamba2-7b's decode step at its published widths (batch 16, 2176
+    positions, the caches donated; 5 layers, both shared blocks applied)
+    copies none of its SSM states or KV caches whole, and needs less
+    temporary memory than one application's K cache."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models.transformer import init_model
+    from repro.train.steps import StepConfig, init_caches, make_decode_step
+
+    cfg = dataclasses.replace(get_config("zamba2-7b"), n_layers=5,
+                              hybrid_layer_ids=(1, 3))
+    batch, max_seq = 16, 2176
+    put = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda k: init_model(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0)))
+    caches = jax.tree.map(put, jax.eval_shape(
+        lambda: init_caches(cfg, batch, max_seq)))
+    tok = put(jax.ShapeDtypeStruct((batch, 1), jnp.int32))
+    step = make_decode_step(cfg, StepConfig(remat=False))
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        params, {"tokens": tok}, caches).compile()
+    copied = set(re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(",
+                            compiled.as_text()))
+    for leaf in jax.tree.leaves(caches):
+        assert ",".join(map(str, leaf.shape)) not in copied, leaf.shape
+    kv = caches[1]
+    app_k = kv.k.size * kv.k.dtype.itemsize // len(cfg.hybrid_layer_ids)
+    assert compiled.memory_analysis().temp_size_in_bytes < app_k
